@@ -47,9 +47,7 @@ from __future__ import annotations
 
 import json
 import os
-import re
 import subprocess
-import sys
 import time
 
 import numpy as np
@@ -80,25 +78,14 @@ def _git_sha() -> str | None:
 def _tier1_test_count() -> int | None:
     """Tier-1 test count for history attribution.
 
-    REPRO_TIER1_COUNT wins (CI sets it to the passing count of the run
-    that just gated this benchmark); the fallback counts *selected*
-    tests via a pytest --collect-only subprocess — the two agree
-    whenever the suite is green with no skips, which is the only state
-    the benchmark lane runs in.  None if neither is available."""
+    REPRO_TIER1_COUNT (CI sets it to the passing count of the run that
+    just gated this benchmark); None where it is unset.  No pytest child
+    is started: this process has already used JAX, and a child that
+    imports it would contend for the same accelerator."""
     env = os.environ.get("REPRO_TIER1_COUNT")
-    if env:
-        try:
-            return int(env)
-        except ValueError:
-            return None
     try:
-        proc = subprocess.run(
-            [sys.executable, "-m", "pytest", "--collect-only", "-q"],
-            cwd=REPO_ROOT, capture_output=True, text=True, timeout=300,
-        )
-        m = re.search(r"(\d+)(?:/\d+)? tests collected", proc.stdout)
-        return int(m.group(1)) if m else None
-    except (OSError, subprocess.SubprocessError):
+        return int(env) if env else None
+    except ValueError:
         return None
 
 N_RANKS = 64
@@ -923,4 +910,7 @@ def run() -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

@@ -202,4 +202,7 @@ def run() -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

@@ -146,6 +146,10 @@ def _dots_and_whiles(model, table) -> tuple[int, int]:
 def main() -> int:
     import jax.numpy as jnp
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from repro.parallel.fabric import fabric_names
 
     # 1. O(period) HLO for every registered fabric.  On this single

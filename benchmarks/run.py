@@ -12,6 +12,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         fig1_compute_knee,
         fig2_matchings,

@@ -152,8 +152,10 @@ def main() -> None:
     if args.mesh:
         import jax
 
+        from repro.parallel import auto_mesh
+
         n = jax.device_count()
-        mesh = jax.make_mesh((max(n // 4, 1), min(n, 4)), ("data", "model"))
+        mesh = auto_mesh((max(n // 4, 1), min(n, 4)), ("data", "model"))
 
     data_cfg = DataConfig(
         vocab_size=cfg.vocab_size, seq_len=args.seq, global_batch=args.batch

@@ -9,8 +9,12 @@ Grid: (E, C/BC, F/BF) with the expert-FFN width F as the innermost
     h   = silu(g) * u                 (VPU, f32)
     acc += h @ w_down_blk             [BC, d]    (MXU, f32 accumulator)
 
-VMEM working set (bf16, d=8192, BC=128, BF=128):
-    x 2MB + w_gate 2MB + w_up 2MB + w_down 2MB + acc(f32) 4MB = 12MB.
+VMEM working set: Mosaic double-buffers every input and output block
+across grid steps, so each counts twice; the f32 accumulators and the
+[BC, BF] f32 intermediates count once (``*_vmem_bytes`` below).  Forward
+at d=4096, BC=256, BF=512, bf16: (2 + 3*4) MiB inputs x2 + 2 MiB out x2
++ 4 MiB acc + 1.5 MiB = 37.5 MiB — past the 16 MiB default scoped
+limit, so the launch asks for more (``vmem_limit_bytes``).
 All matmul dims are multiples of 128 (MXU-aligned).
 """
 
@@ -23,21 +27,72 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_MIB = 1024 * 1024
+# Mosaic's default scoped-VMEM limit on v4/v5e; a kernel whose working
+# set exceeds it must raise the limit or the compiler refuses it.
+_SCOPED_VMEM_DEFAULT = 16 * _MIB
+# The most any launch asks for: v5e cores hold 128 MiB of VMEM, and the
+# compiler keeps some of it for internal scratch.
+VMEM_LIMIT_MAX = 96 * _MIB
 
-def _compiler_params(interpret: bool):
+
+def fwd_vmem_bytes(bc: int, bf: int, d: int, dtype_bytes: int) -> int:
+    """Forward working set: double-buffered x/w_gate/w_up/w_down input
+    blocks and output block, the f32 accumulator, the g/u/h tiles."""
+    blocks = bc * d + 3 * d * bf
+    return (
+        2 * blocks * dtype_bytes
+        + 2 * bc * d * dtype_bytes
+        + 4 * bc * d
+        + 4 * 3 * bc * bf
+    )
+
+
+def dgrad_vmem_bytes(bc: int, bf: int, d: int, dtype_bytes: int) -> int:
+    """dgrad working set: double-buffered go/x row blocks, three weight
+    tiles and the dx block, the f32 accumulator, seven [BC, BF] f32
+    intermediates of the SwiGLU backward."""
+    blocks = 2 * bc * d + 3 * d * bf
+    return (
+        2 * blocks * dtype_bytes
+        + 2 * bc * d * dtype_bytes
+        + 4 * bc * d
+        + 4 * 7 * bc * bf
+    )
+
+
+def wgrad_vmem_bytes(bc: int, bf: int, d: int, dtype_bytes: int) -> int:
+    """wgrad working set: double-buffered go/x row blocks, three weight
+    tiles and three weight-grad blocks, three f32 accumulators
+    ([d, BF] x2 + [BF, d]), seven [BC, BF] f32 intermediates."""
+    blocks = 2 * bc * d + 3 * d * bf
+    return (
+        2 * blocks * dtype_bytes
+        + 2 * 3 * d * bf * dtype_bytes
+        + 4 * 3 * d * bf
+        + 4 * 7 * bc * bf
+    )
+
+
+def _compiler_params(interpret: bool, vmem_bytes: int):
     """Mosaic grid semantics: expert and row-block dims are parallel, the
-    F (accumulation) dim is sequential.  This is the double-buffer hook
-    for phase-pipelined dispatch: Mosaic pipelines block copies across
-    grid steps (fetch block k+1's VMEM tiles while block k is on the
-    MXU), so each phase's envelope-sized launch overlaps its own HBM
+    accumulation dim (last) is sequential.  This is the double-buffer
+    hook for phase-pipelined dispatch: Mosaic pipelines block copies
+    across grid steps (fetch block k+1's VMEM tiles while block k is on
+    the MXU), so each phase's envelope-sized launch overlaps its own HBM
     traffic — and, marked parallel, independent row blocks of the next
-    phase's launch need not serialize behind this one.  Interpret mode
-    (CPU) has no Mosaic pipeline; passing params there is a no-op risk
-    surface, so we skip it."""
+    phase's launch need not serialize behind this one.  A working set
+    past the default scoped limit raises ``vmem_limit_bytes`` to it plus
+    a quarter for what the estimate leaves out.  Interpret mode (CPU) has
+    no Mosaic pipeline and takes no params."""
     if interpret:
         return None
-    return pltpu.TPUCompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary")
+    limit = None
+    if vmem_bytes > _SCOPED_VMEM_DEFAULT:
+        limit = min(-(-vmem_bytes * 5 // 4 // _MIB) * _MIB, VMEM_LIMIT_MAX)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=limit,
     )
 
 
@@ -137,10 +192,6 @@ def moe_gemm_grouped_pallas(
         out_specs=pl.BlockSpec((1, bc, d), lambda e, i, k, m: (e, i, 0)),
         scratch_shapes=[pltpu.VMEM((bc, d), jnp.float32)],
     )
-    kwargs = {}
-    params = _compiler_params(interpret)
-    if params is not None:
-        kwargs["compiler_params"] = params
     return pl.pallas_call(
         functools.partial(
             _grouped_kernel, n_fblocks=n_fblocks, n_cblocks=n_cblocks
@@ -148,7 +199,9 @@ def moe_gemm_grouped_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((e, c, d), x.dtype),
         interpret=interpret,
-        **kwargs,
+        compiler_params=_compiler_params(
+            interpret, fwd_vmem_bytes(bc, bf, d, x.dtype.itemsize)
+        ),
     )(block_meta, x, w_gate, w_up, w_down)
 
 
@@ -306,10 +359,6 @@ def moe_gemm_grouped_pallas_dgrad(
         out_specs=pl.BlockSpec((1, bc, d), lambda e, i, k, m: (e, i, 0)),
         scratch_shapes=[pltpu.VMEM((bc, d), jnp.float32)],
     )
-    kwargs = {}
-    params = _compiler_params(interpret)
-    if params is not None:
-        kwargs["compiler_params"] = params
     return pl.pallas_call(
         functools.partial(
             _grouped_dgrad_kernel, n_fblocks=n_fblocks, n_cblocks=n_cblocks
@@ -317,7 +366,9 @@ def moe_gemm_grouped_pallas_dgrad(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((e, c, d), x.dtype),
         interpret=interpret,
-        **kwargs,
+        compiler_params=_compiler_params(
+            interpret, dgrad_vmem_bytes(bc, bf, d, x.dtype.itemsize)
+        ),
     )(block_meta, go, x, w_gate, w_up, w_down)
 
 
@@ -369,10 +420,6 @@ def moe_gemm_grouped_pallas_wgrad(
             pltpu.VMEM((bf, d), jnp.float32),
         ],
     )
-    kwargs = {}
-    params = _compiler_params(interpret)
-    if params is not None:
-        kwargs["compiler_params"] = params
     return pl.pallas_call(
         functools.partial(
             _grouped_wgrad_kernel, n_fblocks=n_fblocks, n_cblocks=n_cblocks
@@ -384,7 +431,9 @@ def moe_gemm_grouped_pallas_wgrad(
             jax.ShapeDtypeStruct((e, f, d), w_down.dtype),
         ),
         interpret=interpret,
-        **kwargs,
+        compiler_params=_compiler_params(
+            interpret, wgrad_vmem_bytes(bc, bf, d, x.dtype.itemsize)
+        ),
     )(block_meta, go, x, w_gate, w_up, w_down)
 
 
@@ -408,10 +457,6 @@ def moe_gemm_pallas(
     assert c % bc == 0 and f % bf == 0, (c, bc, f, bf)
     n_fblocks = f // bf
     grid = (e, c // bc, n_fblocks)
-    kwargs = {}
-    params = _compiler_params(interpret)
-    if params is not None:
-        kwargs["compiler_params"] = params
     return pl.pallas_call(
         functools.partial(_kernel, n_fblocks=n_fblocks),
         grid=grid,
@@ -425,5 +470,7 @@ def moe_gemm_pallas(
         out_shape=jax.ShapeDtypeStruct((e, c, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, d), jnp.float32)],
         interpret=interpret,
-        **kwargs,
+        compiler_params=_compiler_params(
+            interpret, fwd_vmem_bytes(bc, bf, d, x.dtype.itemsize)
+        ),
     )(x, w_gate, w_up, w_down)

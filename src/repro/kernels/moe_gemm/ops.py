@@ -1,13 +1,15 @@
-"""Public wrapper for the grouped expert GEMM: block-size autotuning,
+"""Public wrapper for the grouped expert GEMM: block-size selection,
 backend-based interpret selection, and a shape-fit fallback.
 
-On CPU (this container) the kernel body runs in ``interpret=True`` mode;
-on TPU ``interpret=False`` is selected automatically from
-``jax.default_backend()``.  Block sizes come from a small autotune table
-keyed on ``(C, d, f)`` — entries measured on TPUv4-class VMEM (~16 MB);
-anything not in the table uses the divisor/VMEM-budget heuristic.  Shapes
-the kernel cannot tile at all (C or f with no usable block divisor) fall
-back to the einsum oracle, so ``moe_gemm`` is always safe to call.
+On CPU the kernel body runs in ``interpret=True`` mode; on TPU
+``interpret=False`` is selected automatically from
+``jax.default_backend()``.  Block sizes come from a small table keyed on
+``(C, d, f)`` — entries the v5e compiler accepts (compiled for a
+described v5e chip; none has been timed) — and anything not in the
+table uses the divisor/VMEM-budget heuristic.  Table hits pass the same
+VMEM check as the heuristic.  Shapes the kernel cannot tile at all (C
+or f with no usable block divisor) fall back to the einsum oracle, so
+``moe_gemm`` is always safe to call.
 """
 
 from __future__ import annotations
@@ -19,31 +21,37 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.moe_gemm.kernel import (
+    dgrad_vmem_bytes,
+    fwd_vmem_bytes,
     moe_gemm_grouped_pallas,
     moe_gemm_grouped_pallas_dgrad,
     moe_gemm_grouped_pallas_wgrad,
     moe_gemm_pallas,
+    wgrad_vmem_bytes,
 )
 from repro.kernels.moe_gemm.ref import moe_gemm_ref
 
-# Measured-good block shapes per (C, d, f) — the MoE launcher's common
-# cells (capacity x d_model x d_ff_expert).  Values are (block_c, block_f).
+# Block shapes per (C, d, f) that compile for v5e — the MoE launcher's
+# common cells (capacity x d_model x d_ff_expert).  Values are
+# (block_c, block_f): the widest row block for which the forward and
+# the backward (which shares block_c) both fit the VMEM budget, since
+# every row block streams the expert's whole weights once.
 AUTOTUNE_TABLE: dict[tuple[int, int, int], tuple[int, int]] = {
-    # Mixtral-8x7B-ish: d=4096, f=14336
+    # Mixtral-8x7B: d=4096, f=14336
     (256, 4096, 14336): (256, 512),
-    (512, 4096, 14336): (256, 512),
-    (1024, 4096, 14336): (256, 512),
-    (2048, 4096, 14336): (512, 512),
-    # DBRX-ish (dbrx_132b): d=6144, f=10752
+    (512, 4096, 14336): (512, 256),
+    (1024, 4096, 14336): (512, 256),
+    (2048, 4096, 14336): (512, 256),
+    # DBRX (dbrx_132b): d=6144, f=10752
     (256, 6144, 10752): (256, 256),
     (512, 6144, 10752): (256, 256),
     (1024, 6144, 10752): (256, 256),
     (2048, 6144, 10752): (256, 256),
-    # Qwen3-MoE-ish fine-grained experts (qwen3_moe_235b): d=4096, f=1536
+    # Qwen3-MoE fine-grained experts (qwen3_moe_235b): d=4096, f=1536
     (256, 4096, 1536): (256, 512),
-    (512, 4096, 1536): (256, 512),
-    (1024, 4096, 1536): (512, 512),
-    (2048, 4096, 1536): (512, 512),
+    (512, 4096, 1536): (512, 256),
+    (1024, 4096, 1536): (512, 256),
+    (2048, 4096, 1536): (512, 256),
     # test/bench shapes
     (128, 64, 128): (128, 128),
     (256, 128, 256): (128, 128),
@@ -53,9 +61,7 @@ AUTOTUNE_TABLE: dict[tuple[int, int, int], tuple[int, int]] = {
 # block_c (dgrad and wgrad index the same scalar-prefetched occupancy
 # table), but wgrad holds three f32 accumulators (12 * d * block_f
 # bytes), so the forward's wide f tiles blow VMEM — the backward runs a
-# narrower f tile.  block_f=128 keeps the accumulators at 6.3 MB for
-# d=4096 / 9.4 MB for d=6144, inside the budget with the five input
-# blocks double-buffered.
+# narrower f tile.
 AUTOTUNE_TABLE_BWD: dict[tuple[int, int, int], int] = {
     (256, 4096, 14336): 128,
     (512, 4096, 14336): 128,
@@ -73,16 +79,18 @@ AUTOTUNE_TABLE_BWD: dict[tuple[int, int, int], int] = {
     (256, 128, 256): 128,
 }
 
-# Conservative VMEM working-set budget (bytes): x + w_gate + w_up + w_down
-# blocks + the f32 accumulator must fit with double-buffering headroom.
-_VMEM_BUDGET = 12 * 1024 * 1024
+# VMEM working-set budget (bytes) for block selection.  The kernel asks
+# for its estimate plus a quarter (``vmem_limit_bytes``): at most 60 MiB,
+# well inside the most a launch may request (``VMEM_LIMIT_MAX``).
+_VMEM_BUDGET = 48 * 1024 * 1024
 
 
-def _vmem_bytes(bc: int, bf: int, d: int, dtype_bytes: int) -> int:
-    x = bc * d * dtype_bytes
-    w = 2 * d * bf * dtype_bytes + bf * d * dtype_bytes
-    acc = bc * d * 4
-    return x + w + acc
+def _bwd_vmem_bytes(bc: int, bf: int, d: int, dtype_bytes: int) -> int:
+    """The backward's working set: the larger of its two launches."""
+    return max(
+        dgrad_vmem_bytes(bc, bf, d, dtype_bytes),
+        wgrad_vmem_bytes(bc, bf, d, dtype_bytes),
+    )
 
 
 def _divisor_blocks(dim: int, floor: int) -> list[int]:
@@ -100,29 +108,27 @@ def select_block_sizes(
 ) -> tuple[int, int] | None:
     """Pick (block_c, block_f) for the grid, or None if untileable.
 
-    Table hit wins; otherwise take the largest divisor blocks whose VMEM
-    working set fits the budget.  Compiled TPU mode requires MXU-friendly
-    blocks (>=128 on both tile dims); interpret mode only needs divisors.
+    A table hit wins if its VMEM working set fits the budget; otherwise
+    take the largest divisor blocks (row block first) whose working set
+    fits.  Compiled TPU mode requires MXU-friendly blocks (>=128 on both
+    tile dims); interpret mode only needs divisors.
     """
     hit = AUTOTUNE_TABLE.get((c, d, f))
-    if hit is not None and c % hit[0] == 0 and f % hit[1] == 0:
+    if (
+        hit is not None
+        and c % hit[0] == 0
+        and f % hit[1] == 0
+        and fwd_vmem_bytes(*hit, d, dtype_bytes) <= _VMEM_BUDGET
+    ):
         return hit
     floor = 8 if interpret else 128
     cands_c = _divisor_blocks(c, floor) or ([c] if (interpret and c > 0) else [])
     cands_f = _divisor_blocks(f, floor) or ([f] if (interpret and f > 0) else [])
     for bc in cands_c:
         for bf in cands_f:
-            if _vmem_bytes(bc, bf, d, dtype_bytes) <= _VMEM_BUDGET:
+            if fwd_vmem_bytes(bc, bf, d, dtype_bytes) <= _VMEM_BUDGET:
                 return bc, bf
     return None
-
-
-def _bwd_vmem_bytes(bc: int, bf: int, d: int, dtype_bytes: int) -> int:
-    """wgrad working set (the backward's VMEM hot spot): go + x row
-    blocks, three weight tiles, and the three f32 accumulators."""
-    blocks = 2 * bc * d * dtype_bytes + 3 * d * bf * dtype_bytes
-    accs = 12 * d * bf  # [d, bf] x2 + [bf, d], f32
-    return blocks + accs
 
 
 def select_backward_block_f(
@@ -139,14 +145,18 @@ def select_backward_block_f(
 
     ``block_c`` is fixed to the forward's choice — dgrad and wgrad index
     the forward's scalar-prefetched occupancy table, which is laid out
-    per forward row block.  Table hit wins; otherwise the largest f
-    divisor whose wgrad working set (three f32 accumulators dominate)
-    fits the VMEM budget."""
+    per forward row block.  A table hit wins if it fits the VMEM budget;
+    otherwise the largest f divisor whose dgrad and wgrad working sets
+    fit."""
     bc = min(block_c, c)
     if c % bc:
         return None
     hit = AUTOTUNE_TABLE_BWD.get((c, d, f))
-    if hit is not None and f % hit == 0:
+    if (
+        hit is not None
+        and f % hit == 0
+        and _bwd_vmem_bytes(bc, hit, d, dtype_bytes) <= _VMEM_BUDGET
+    ):
         return hit
     floor = 8 if interpret else 128
     cands_f = _divisor_blocks(f, floor) or ([f] if (interpret and f > 0) else [])

@@ -1,3 +1,3 @@
 # Launch layer: production mesh, dry-run driver, roofline extraction,
 # train/serve entry points.  NOTE: importing this package must NOT touch
-# jax device state (dryrun.py sets XLA_FLAGS before any jax import).
+# jax device state (dryrun.main sets XLA_FLAGS before first jax use).

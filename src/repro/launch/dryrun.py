@@ -1,8 +1,3 @@
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-# ruff: noqa: E402  (the two lines above MUST precede any jax import)
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this proves the distribution config is coherent at
@@ -24,6 +19,7 @@ Artifacts land in reports/dryrun/<mesh>/<arch>.<cell>[.<dispatch>].json.
 import argparse
 import dataclasses
 import json
+import os
 import sys
 import time
 import traceback
@@ -391,6 +387,12 @@ def run_cell(
 
 
 def main(argv=None) -> int:
+    # the 512 placeholder devices: takes effect only if no jax backend
+    # has started in this process yet (make_production_mesh says so)
+    os.environ["XLA_FLAGS"] = " ".join(
+        filter(None, [os.environ.get("XLA_FLAGS"),
+                      "--xla_force_host_platform_device_count=512"])
+    )
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, help="one arch id (default: all)")
     ap.add_argument("--cells", default=None, help="comma list (default: all)")

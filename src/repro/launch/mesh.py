@@ -1,13 +1,15 @@
 """Production mesh construction.
 
 Kept as functions (never module-level constants) so importing this module
-never initializes jax device state — the dry-run must set XLA_FLAGS
+never initializes jax device state — the dry-run sets XLA_FLAGS
 before first jax use.
 """
 
 from __future__ import annotations
 
 import jax
+
+from repro.parallel import auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -27,9 +29,9 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices, have {len(devices)} — run under "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=512"
         )
-    return jax.make_mesh(shape, axes, devices=devices)
+    return auto_mesh(shape, axes, devices=devices)
 
 
 def make_debug_mesh(shape=(2, 4), axes=("data", "model")):
     """Small mesh for multi-device CPU tests."""
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)

@@ -29,9 +29,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.rules import dtype_policy, serve_rules
 from repro.models import Model
-from repro.parallel import axis_rules
+from repro.parallel import auto_mesh, axis_rules
 
 log = logging.getLogger("repro.launch.serve")
 
@@ -57,6 +58,7 @@ def _make_controller(cfg, args, n_ranks: int):
 
 def main(argv=None) -> None:
     logging.basicConfig(level=logging.INFO, format="%(message)s")
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-8b")
     ap.add_argument("--smoke", action="store_true")
@@ -85,7 +87,7 @@ def main(argv=None) -> None:
     mesh = None
     if jax.device_count() > 1:
         n = jax.device_count()
-        mesh = jax.make_mesh((max(n // 4, 1), min(n, 4)), ("data", "model"))
+        mesh = auto_mesh((max(n // 4, 1), min(n, 4)), ("data", "model"))
 
     runtime = scenario = None
     if args.controller:

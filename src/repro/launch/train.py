@@ -22,9 +22,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, smoke_config
 from repro.data import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.rules import train_rules
 from repro.models import Model
-from repro.parallel import axis_rules
+from repro.parallel import auto_mesh, axis_rules
 from repro.train import TrainLoopConfig, train_loop
 
 log = logging.getLogger("repro.launch.train")
@@ -37,11 +38,26 @@ def build_mesh():
         if n % cand == 0 and cand <= n:
             model_ax = cand
             break
-    return jax.make_mesh((n // model_ax, model_ax), ("data", "model"))
+    return auto_mesh((n // model_ax, model_ax), ("data", "model"))
+
+
+def batch_sharder(mesh):
+    """Host batch -> device arrays split over the mesh's 'data' axis."""
+
+    def shard_batch(b):
+        return {
+            k: jax.device_put(
+                v, NamedSharding(mesh, P("data", *([None] * (v.ndim - 1))))
+            )
+            for k, v in b.items()
+        }
+
+    return shard_batch
 
 
 def main(argv=None) -> None:
     logging.basicConfig(level=logging.INFO, format="%(message)s")
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mixtral-8x7b")
     ap.add_argument("--steps", type=int, default=100)
@@ -137,16 +153,10 @@ def main(argv=None) -> None:
         log_every=10,
     )
 
-    def shard_batch(b):
-        return {
-            k: jax.device_put(
-                v, NamedSharding(mesh, P("data", *([None] * (v.ndim - 1))))
-            )
-            for k, v in b.items()
-        }
-
     with axis_rules(mesh, train_rules()):
-        res = train_loop(model, data_cfg, loop_cfg, shard_batch=shard_batch)
+        res = train_loop(
+            model, data_cfg, loop_cfg, shard_batch=batch_sharder(mesh)
+        )
     log.info("done: step %d loss %.4f (%d failures recovered)",
              res["final_step"], res["final_loss"], res["failures"])
 

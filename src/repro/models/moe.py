@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.hierarchical import HierarchicalTable
 from repro.core.schedule import ScheduleTable
-from repro.parallel import current_rules, shard_map_compat
+from repro.parallel import current_rules
 from repro.parallel.fabric import geometry as _geom
 from repro.parallel.fabric.base import (
     FabricContext,
@@ -304,7 +304,7 @@ def _moe_ep_pipeline(
         y, stats = res
         return y.astype(xb.dtype).reshape(bl, s_loc, d), stats
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
     res = fn(

@@ -1,11 +1,11 @@
 from repro.parallel.sharding import (
     AxisRules,
     DEFAULT_RULES,
+    auto_mesh,
     axis_rules,
     current_rules,
     logical_to_spec,
     shard,
-    shard_map_compat,
 )
 
 # the dispatch-backend registry (one MoE pipeline over pluggable
@@ -16,10 +16,10 @@ from repro.parallel import fabric
 __all__ = [
     "AxisRules",
     "DEFAULT_RULES",
+    "auto_mesh",
     "axis_rules",
     "current_rules",
     "fabric",
     "logical_to_spec",
     "shard",
-    "shard_map_compat",
 ]

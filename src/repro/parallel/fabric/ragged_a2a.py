@@ -12,14 +12,12 @@ for every pair the plan left dark: ``envelope[k]`` slots cross per live
 pair, nothing else.  That is the number the bytes bench counts for a
 circuit fabric — this backend makes the TPU wire match the model.
 
-Availability: ``jax.lax.ragged_all_to_all`` landed after the pinned jax
-in this container, and compiled support targets TPU.  Off-TPU (or on an
-older jax) the backend **falls back to the parent's dense emulation** —
-same admission numerics, same results, emulation bytes — so configs
-naming ``ragged_a2a`` run everywhere and light up the ragged path when
-the hardware can serve it.  ``REPRO_FORCE_RAGGED=1`` forces the ragged
-primitive wherever the installed jax exposes it (interpret-style CPU
-runs on newer jax).
+Availability: compiled support for ``jax.lax.ragged_all_to_all``
+targets TPU.  Off-TPU the backend **falls back to the parent's dense
+emulation** — same admission numerics, same results, emulation bytes —
+so configs naming ``ragged_a2a`` run everywhere and take the ragged path
+on the TPU.  ``REPRO_FORCE_RAGGED=1`` forces the ragged path on any
+backend (the multi-device CPU suite does, with the primitive stubbed).
 """
 
 from __future__ import annotations
@@ -37,13 +35,12 @@ from repro.parallel.fabric.phase_pipelined import (
     _PhaseMeta,
 )
 
-_RAGGED = getattr(jax.lax, "ragged_all_to_all", None)
+# module seam: the multi-device CPU suite swaps in a stub
+_RAGGED = jax.lax.ragged_all_to_all
 
 
 def ragged_available() -> bool:
-    """Can this process run the ragged primitive (vs the emulation)?"""
-    if _RAGGED is None:
-        return False
+    """Does this process take the ragged primitive (vs the emulation)?"""
     if os.environ.get("REPRO_FORCE_RAGGED"):
         return True
     return jax.default_backend() == "tpu"

@@ -78,9 +78,7 @@ def gpipe(stage_fn, stage_params, x, *, mesh, axis: str, n_micro: int):
         mask = (me == p_stages - 1).astype(outs.dtype)
         return jax.lax.psum(outs * mask, axis)
 
-    from repro.parallel.sharding import shard_map_compat
-
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
     return fn(stage_params, x)

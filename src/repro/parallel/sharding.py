@@ -21,16 +21,29 @@ import threading
 from collections.abc import Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
     "AxisRules",
     "DEFAULT_RULES",
+    "auto_mesh",
     "axis_rules",
     "current_rules",
     "logical_to_spec",
     "shard",
 ]
+
+
+def auto_mesh(shape, axes, *, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis in Auto mode.
+
+    The model code places tensors with ``with_sharding_constraint`` and
+    leaves the rest to the compiler's propagation, which needs Auto axes;
+    ``jax.make_mesh`` defaults to Explicit axes, under which those
+    constraints are refused."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes), devices=devices
+    )
 
 # logical name -> physical mesh axis (or tuple of axes), tried in order.
 DEFAULT_RULES: dict[str, tuple[str, ...] | str | None] = {
@@ -151,22 +164,3 @@ def shard(x: jax.Array, *logical: str | None) -> jax.Array:
     spec = logical_to_spec(logical, x.shape)
     return jax.lax.with_sharding_constraint(x, NamedSharding(ar.mesh, spec))
 
-
-def shard_map_compat(body, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=)``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)`` (the same
-    replication-check knob under its old name).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )

@@ -8,10 +8,11 @@ Public API:
                                admission, device-controller loop with
                                schedule-regime warm-swap (engine.py)
     ServeMetrics             — serving telemetry (metrics.py)
+    init_serve_params        — seeded weights in the serving dtype
 """
 
 from repro.serve.batcher import ContinuousBatcher
-from repro.serve.engine import ServeEngine
+from repro.serve.engine import ServeEngine, init_serve_params
 from repro.serve.metrics import ServeMetrics, percentiles
 from repro.serve.queue import Request, RequestQueue
 
@@ -21,5 +22,6 @@ __all__ = [
     "RequestQueue",
     "ServeEngine",
     "ServeMetrics",
+    "init_serve_params",
     "percentiles",
 ]

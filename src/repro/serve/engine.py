@@ -48,12 +48,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.rules import dtype_policy
 from repro.models import Model
 from repro.serve.batcher import ContinuousBatcher
 from repro.serve.metrics import ServeMetrics
 from repro.serve.queue import Request, RequestQueue
 
-__all__ = ["ServeEngine"]
+__all__ = ["ServeEngine", "init_serve_params"]
+
+
+def init_serve_params(cfg, seed: int = 0) -> dict:
+    """Seeded weights in the serving dtype (bf16), initialized and cast
+    under one jit, so the f32 initial values never fill the device."""
+    dt = dtype_policy(cfg)["serve_param_dtype"]
+    model = Model(cfg)
+    return jax.jit(
+        lambda key: jax.tree.map(lambda a: a.astype(dt), model.init(key))
+    )(jax.random.PRNGKey(seed))
 
 
 class ServeEngine:

@@ -1,9 +1,13 @@
 """Fault-tolerant training loop.
 
 Production behaviors exercised here (and tested in multidev_train.py):
+* state placed on the active mesh from the start: parameters, moments
+  and error-feedback buffers are initialized under one jit straight into
+  their ``param_specs`` shardings, so no device ever holds the whole
+  unsharded state,
 * resume-from-latest on start (elastic: restore works across mesh shapes
   because checkpoints are stored unsharded; the new mesh's shardings are
-  applied at device_put),
+  applied at device_put); ``ckpt_dir=None`` runs without checkpoints,
 * periodic async checkpointing off the critical path,
 * retry-on-failure: a step that raises (injected in tests; an XLA/ICI
   error in production) rolls back to the last checkpoint and continues,
@@ -34,6 +38,7 @@ from typing import Callable
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager
 from repro.core.faults import FabricFaultError, NonFiniteLossError
@@ -43,7 +48,8 @@ from repro.parallel.fabric import (
     consumes_schedule as _fabric_consumes,
     consumes_table as _fabric_consumes_table,
 )
-from repro.train.train_step import make_train_step
+from repro.parallel.sharding import current_rules
+from repro.train.train_step import make_train_step, param_specs
 
 log = logging.getLogger("repro.train")
 
@@ -51,7 +57,7 @@ log = logging.getLogger("repro.train")
 @dataclasses.dataclass
 class TrainLoopConfig:
     steps: int = 100
-    ckpt_dir: str = "/tmp/repro_ckpt"
+    ckpt_dir: str | None = "/tmp/repro_ckpt"  # None: no checkpoints
     ckpt_every: int = 50
     keep: int = 3
     microbatches: int = 1
@@ -79,7 +85,8 @@ def train_loop(
     device_controller=None,
     device_ctrl_state=None,
 ) -> dict:
-    """Run (or resume) training.  Returns final metrics/history.
+    """Run (or resume) training.  Returns final metrics/history, the
+    final state and the jitted step.
 
     shard_batch: optional fn(dict of np arrays) -> device arrays with the
       mesh's batch sharding (identity when single-device).
@@ -218,9 +225,13 @@ def train_loop(
     # (Degradation-chain fabric switches are the exception: each rebuilds
     # the step on a different backend — a deliberate, counted recompile.)
     step_fn = build_step(model)
-    manager = CheckpointManager(loop_cfg.ckpt_dir, keep=loop_cfg.keep)
+    manager = (
+        CheckpointManager(loop_cfg.ckpt_dir, keep=loop_cfg.keep)
+        if loop_cfg.ckpt_dir
+        else None
+    )
 
-    def fresh_state():
+    def init_state():
         params = model.init(jax.random.PRNGKey(0))
         opt_state = opt.init(params)
         ef_state = (
@@ -228,8 +239,29 @@ def train_loop(
         )
         return {"params": params, "opt": opt_state, "ef": ef_state}
 
+    # On a mesh every state leaf takes its parameter's sharding (moments
+    # and error feedback mirror the parameter paths; the step counter is
+    # replicated).  Off-mesh: the default device.
+    ar = current_rules()
+    shardings = None
+    if ar is not None and ar.mesh is not None:
+        shardings = jax.tree.map(
+            lambda spec: NamedSharding(ar.mesh, spec),
+            param_specs(jax.eval_shape(init_state)),
+            is_leaf=lambda x: isinstance(x, P),
+        )
+    fresh_state = jax.jit(init_state, out_shardings=shardings)
+
+    def restore_latest(template):
+        if manager is None:
+            return None, None
+        ck_step, restored = manager.restore_latest(template)
+        if restored is not None and shardings is not None:
+            restored = jax.device_put(restored, shardings)
+        return ck_step, restored
+
     state = fresh_state()
-    start_step, restored = manager.restore_latest(state)
+    start_step, restored = restore_latest(state)
     if restored is not None:
         state = restored
         log.info("resumed from step %d", start_step)
@@ -401,9 +433,10 @@ def train_loop(
                 # rolled-back step then executes a plan the fabric can
                 # honor — bounded by the same failure budget)
                 runtime.record_fault(err)
-            manager.wait()
+            if manager is not None:
+                manager.wait()
             template = fresh_state()
-            ck_step, restored = manager.restore_latest(template)
+            ck_step, restored = restore_latest(template)
             if restored is not None:
                 state, step = restored, ck_step
             else:
@@ -452,14 +485,22 @@ def train_loop(
             history.append(entry)
             log.info("step %d loss %.4f (%.3fs/step)", step, loss, dt_step)
         step += 1
-        if step % loop_cfg.ckpt_every == 0 or step == loop_cfg.steps:
+        if manager is not None and (
+            step % loop_cfg.ckpt_every == 0 or step == loop_cfg.steps
+        ):
             manager.save_async(step, state)
-    manager.wait()
+    if manager is not None:
+        manager.wait()
     out = {
         "history": history,
         "final_step": step,
         "failures": failures,
         "final_loss": history[-1]["loss"] if history else float("nan"),
+        # the trained state and the jitted step that produced it (its
+        # compiled program: ``step_fn.lower(...).compile()`` hits the
+        # executable cache)
+        "state": state,
+        "step_fn": step_fn,
     }
     if runtime is not None:
         # honest compile count, read off the jit executable cache:

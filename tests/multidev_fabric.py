@@ -14,8 +14,6 @@ from __future__ import annotations
 
 import os
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -34,7 +32,7 @@ from repro.core import (
     plan_schedule,
 )
 from repro.models import moe
-from repro.parallel import axis_rules
+from repro.parallel import auto_mesh, axis_rules
 from repro.parallel.fabric import fabric_names
 
 N_EP = 4
@@ -79,8 +77,10 @@ def traffic_from_routing(params, cfg, x, n):
 
 
 def main() -> None:
-    assert jax.device_count() == 8, jax.device_count()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    assert jax.device_count() == 8, (
+        "run under XLA_FLAGS=--xla_force_host_platform_device_count=8"
+    )
+    mesh = auto_mesh((2, 4), ("data", "model"))
 
     cfg0 = make_cfg("dense")
     params = moe.moe_init(jax.random.PRNGKey(0), cfg0)

@@ -15,10 +15,6 @@ Checks, on a (data=2, model=4) mesh:
 
 from __future__ import annotations
 
-import os
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import dataclasses
 
 import jax
@@ -34,7 +30,7 @@ from repro.configs.base import ModelConfig, MoECfg
 from repro.core import decompose, plan_schedule, ring_schedule
 from repro.models import moe
 from repro.models.model import Model
-from repro.parallel import axis_rules
+from repro.parallel import auto_mesh, axis_rules
 
 
 def make_cfg(dispatch: str) -> ModelConfig:
@@ -74,8 +70,10 @@ def traffic_from_routing(params, cfg, x, n):
 
 
 def main() -> None:
-    assert jax.device_count() == 8, jax.device_count()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    assert jax.device_count() == 8, (
+        "run under XLA_FLAGS=--xla_force_host_platform_device_count=8"
+    )
+    mesh = auto_mesh((2, 4), ("data", "model"))
 
     key = jax.random.PRNGKey(0)
     cfg = make_cfg("dense")

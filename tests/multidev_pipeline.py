@@ -5,14 +5,11 @@ Run via tests/test_multidevice.py (8 fake devices).
 
 from __future__ import annotations
 
-import os
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.parallel import auto_mesh
 from repro.parallel.pipeline import gpipe
 
 
@@ -23,8 +20,10 @@ def stage_fn(params, x):
 
 
 def main() -> None:
-    assert jax.device_count() == 8
-    mesh = jax.make_mesh((2, 4), ("data", "pipe"))
+    assert jax.device_count() == 8, (
+        "run under XLA_FLAGS=--xla_force_host_platform_device_count=8"
+    )
+    mesh = auto_mesh((2, 4), ("data", "pipe"))
     p_stages, d = 4, 16
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
     params = {

@@ -11,10 +11,6 @@
 
 from __future__ import annotations
 
-import os
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import dataclasses
 import shutil
 
@@ -26,7 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import smoke_config
 from repro.data import DataConfig
 from repro.models import Model
-from repro.parallel import axis_rules
+from repro.parallel import auto_mesh, axis_rules
 from repro.train import TrainLoopConfig, train_loop
 
 CKPT = "/tmp/repro_multidev_ckpt"
@@ -52,7 +48,7 @@ def batch_sharder(mesh):
 
 
 def run(mesh_shape, steps, failure_hook=None, ckpt_every=5):
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = auto_mesh(mesh_shape, ("data", "model"))
     cfg, model = make_model()
     data_cfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=16, global_batch=8)
     loop_cfg = TrainLoopConfig(
@@ -75,7 +71,9 @@ def run(mesh_shape, steps, failure_hook=None, ckpt_every=5):
 
 
 def main() -> None:
-    assert jax.device_count() == 8
+    assert jax.device_count() == 8, (
+        "run under XLA_FLAGS=--xla_force_host_platform_device_count=8"
+    )
 
     # --- clean run -----------------------------------------------------
     shutil.rmtree(CKPT, ignore_errors=True)
@@ -120,7 +118,7 @@ def main() -> None:
     from repro.core import ControllerConfig, DriftScenario, ScheduleRuntime
 
     shutil.rmtree(CKPT, ignore_errors=True)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     cfg, _ = make_model()
     cfg = dataclasses.replace(
         cfg, moe=dataclasses.replace(cfg.moe, dispatch="scheduled")

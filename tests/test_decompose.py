@@ -3,12 +3,8 @@
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # fall back to the deterministic in-repo sweep
-    from _hyp_compat import given, settings
-    from _hyp_compat import strategies as st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import (
     bvn_coefficients,

@@ -464,15 +464,15 @@ class TestBytesAccounting:
 
 
 class TestRaggedFallback:
-    def test_fallback_is_emulation_off_tpu(self):
+    def test_fallback_is_emulation_off_tpu(self, monkeypatch):
         from repro.parallel.fabric import ragged_available
 
-        # in this container (pinned jax, CPU) the primitive is absent:
-        # the backend must run the parent's dense emulation
-        import jax as _jax
-
-        if getattr(_jax.lax, "ragged_all_to_all", None) is None:
-            assert not ragged_available()
+        # off-TPU, unforced, the backend runs the parent's dense
+        # emulation; forcing it selects the primitive
+        monkeypatch.delenv("REPRO_FORCE_RAGGED", raising=False)
+        assert not ragged_available()
+        monkeypatch.setenv("REPRO_FORCE_RAGGED", "1")
+        assert ragged_available()
 
 
 class TestEnvelopeShrink:

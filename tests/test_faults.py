@@ -21,12 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:
-    from _hyp_compat import given, settings
-    from _hyp_compat import strategies as st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs.base import ModelConfig, MoECfg
 from repro.core import (

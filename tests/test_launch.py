@@ -92,12 +92,8 @@ class TestRooflineMath:
 
 class TestHierarchicalProperty:
     def test_split_is_partition(self):
-        try:
-            from hypothesis import given, settings
-            from hypothesis import strategies as st
-        except ImportError:  # deterministic in-repo sweep
-            from _hyp_compat import given, settings
-            from _hyp_compat import strategies as st
+        from hypothesis import given, settings
+        from hypothesis import strategies as st
 
         from repro.core import split_traffic
 

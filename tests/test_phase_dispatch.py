@@ -16,12 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:
-    from _hyp_compat import given, settings
-    from _hyp_compat import strategies as st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs.base import ModelConfig, MoECfg
 from repro.core import (
@@ -232,7 +228,7 @@ class TestPhaseSlotProperty:
     every admitted remote choice gets a unique slot inside its phase
     block, across random tables and random routings."""
 
-    @settings(max_examples=25)
+    @settings(max_examples=25, deadline=None)
     @given(
         st.integers(min_value=0, max_value=10_000),
         st.integers(min_value=1, max_value=2),
