@@ -77,6 +77,7 @@ import numpy as np
 
 from repro.core.lap_jax import greedy_phases_jax
 from repro.core.schedule import ScheduleTable
+from repro.scopes import scope
 
 __all__ = [
     "DeviceControllerConfig",
@@ -514,10 +515,11 @@ class DeviceController:
         is the fold + EMA + one scatter — the re-plan branch only runs
         when the traced drift signal fires.
         """
-        traffic = routing_to_traffic_traced(
-            routing, n_ranks=self.cfg.n_ranks, n_experts=self.cfg.n_experts
-        )
-        return self.step_traffic(state, traffic, dropped)
+        with scope("controller"):
+            traffic = routing_to_traffic_traced(
+                routing, n_ranks=self.cfg.n_ranks, n_experts=self.cfg.n_experts
+            )
+            return self.step_traffic(state, traffic, dropped)
 
     def step_traffic(
         self,

@@ -40,6 +40,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.scopes import scope
+
 __all__ = [
     "auction_lap",
     "auction_lap_batch",
@@ -248,68 +250,69 @@ def greedy_phases_jax(
       min_cap), quantum)``; 0 on dark slots), valid [L, k_max, n] bool,
       n_phases [L] i32, sent [L, k_max, n] f32, residual [L, n, n] f32.
     """
-    a = jnp.asarray(traffic, jnp.float32)
-    L, n, _ = a.shape
-    eye = jnp.eye(n, dtype=bool)
-    a = jnp.where(eye[None], 0.0, a)
-    usable = (
-        jnp.asarray(mask, bool) & ~eye if mask is not None else ~eye
-    )
-    a = jnp.where(usable[None], a, 0.0)
-    idx = jnp.arange(n, dtype=jnp.int32)
-
-    def one_phase(residual, _):
-        # Unpenalized solve, like the host greedy: dark/diagonal entries
-        # are already zero in the residual, so the LAP parks rows on them
-        # freely (weight 0) when that frees a column for real demand —
-        # ``valid`` filtering keeps those pairs unrouted.  Penalizing
-        # them instead (the standalone ``auction_lap`` mask contract)
-        # would refuse phases that route demand while parking other rows
-        # dark, stranding routable residual the host path admits.
-        perms = auction_lap_batch(residual, max_rounds=max_rounds)
-        sent = jnp.take_along_axis(residual, perms[:, :, None], axis=2)[
-            :, :, 0
-        ]
-        valid = (
-            (sent > 0)
-            & (perms != idx[None, :])
-            & usable[idx[None, :], perms]
+    with scope("lap"):
+        a = jnp.asarray(traffic, jnp.float32)
+        L, n, _ = a.shape
+        eye = jnp.eye(n, dtype=bool)
+        a = jnp.where(eye[None], 0.0, a)
+        usable = (
+            jnp.asarray(mask, bool) & ~eye if mask is not None else ~eye
         )
-        sent = jnp.where(valid, sent, 0.0)
-        residual = jnp.where(
-            valid[:, :, None] & (idx[None, None, :] == perms[:, :, None]),
-            0.0,
-            residual,
-        )
-        # plan_schedule cap rounding on this slot (alloc == sent for
-        # max-weight; dark slots keep cap 0 so the admission mask and
-        # the bytes accounting both see them as free).
-        mx = jnp.max(jnp.where(valid, sent, 0.0), axis=1)
-        any_valid = valid.any(axis=1)
-        cap = jnp.maximum(jnp.ceil(mx * slack), float(min_cap))
-        cap = (-(-cap.astype(jnp.int32) // quantum)) * quantum
-        cap = jnp.where(any_valid, cap, 0).astype(jnp.int32)
-        return residual, (perms, cap, valid, sent)
+        a = jnp.where(usable[None], a, 0.0)
+        idx = jnp.arange(n, dtype=jnp.int32)
 
-    residual, (perms, caps, valid, sent) = jax.lax.scan(
-        one_phase, a, None, length=k_max
-    )
-    # scan stacks on axis 0 -> [k_max, L, ...]; table layout is [L, k_max, ...]
-    perms = jnp.swapaxes(perms, 0, 1)
-    caps = jnp.swapaxes(caps, 0, 1)
-    valid = jnp.swapaxes(valid, 0, 1)
-    sent = jnp.swapaxes(sent, 0, 1)
-    # Any positive residual yields a further matching with sent > 0, so
-    # live slots form a prefix and the phase count is just the live count.
-    n_phases = valid.any(axis=2).sum(axis=1).astype(jnp.int32)
-    # Pad dark slots with the identity perm, like from_schedules.
-    dark = ~valid.any(axis=2)
-    perms = jnp.where(dark[:, :, None], idx[None, None, :], perms)
-    return {
-        "perms": perms.astype(jnp.int32),
-        "caps": caps,
-        "valid": valid,
-        "n_phases": n_phases,
-        "sent": sent,
-        "residual": residual,
-    }
+        def one_phase(residual, _):
+            # Unpenalized solve, like the host greedy: dark/diagonal entries
+            # are already zero in the residual, so the LAP parks rows on them
+            # freely (weight 0) when that frees a column for real demand —
+            # ``valid`` filtering keeps those pairs unrouted.  Penalizing
+            # them instead (the standalone ``auction_lap`` mask contract)
+            # would refuse phases that route demand while parking other rows
+            # dark, stranding routable residual the host path admits.
+            perms = auction_lap_batch(residual, max_rounds=max_rounds)
+            sent = jnp.take_along_axis(residual, perms[:, :, None], axis=2)[
+                :, :, 0
+            ]
+            valid = (
+                (sent > 0)
+                & (perms != idx[None, :])
+                & usable[idx[None, :], perms]
+            )
+            sent = jnp.where(valid, sent, 0.0)
+            residual = jnp.where(
+                valid[:, :, None] & (idx[None, None, :] == perms[:, :, None]),
+                0.0,
+                residual,
+            )
+            # plan_schedule cap rounding on this slot (alloc == sent for
+            # max-weight; dark slots keep cap 0 so the admission mask and
+            # the bytes accounting both see them as free).
+            mx = jnp.max(jnp.where(valid, sent, 0.0), axis=1)
+            any_valid = valid.any(axis=1)
+            cap = jnp.maximum(jnp.ceil(mx * slack), float(min_cap))
+            cap = (-(-cap.astype(jnp.int32) // quantum)) * quantum
+            cap = jnp.where(any_valid, cap, 0).astype(jnp.int32)
+            return residual, (perms, cap, valid, sent)
+
+        residual, (perms, caps, valid, sent) = jax.lax.scan(
+            one_phase, a, None, length=k_max
+        )
+        # scan stacks on axis 0 -> [k_max, L, ...]; table layout is [L, k_max, ...]
+        perms = jnp.swapaxes(perms, 0, 1)
+        caps = jnp.swapaxes(caps, 0, 1)
+        valid = jnp.swapaxes(valid, 0, 1)
+        sent = jnp.swapaxes(sent, 0, 1)
+        # Any positive residual yields a further matching with sent > 0, so
+        # live slots form a prefix and the phase count is just the live count.
+        n_phases = valid.any(axis=2).sum(axis=1).astype(jnp.int32)
+        # Pad dark slots with the identity perm, like from_schedules.
+        dark = ~valid.any(axis=2)
+        perms = jnp.where(dark[:, :, None], idx[None, None, :], perms)
+        return {
+            "perms": perms.astype(jnp.int32),
+            "caps": caps,
+            "valid": valid,
+            "n_phases": n_phases,
+            "sent": sent,
+            "residual": residual,
+        }

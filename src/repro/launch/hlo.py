@@ -19,12 +19,19 @@ their execution multiplier:
     links actually carry (used for the roofline collective term).
     collective-permute wire bytes are scaled by the source-target pair
     fraction (sparse scheduled phases keep idle pairs dark).
+
+``op_scopes`` reads each instruction's ``metadata={op_name=...}`` back
+into the program's named scopes (``repro.scopes.SCOPES``): a profiler
+trace names device ops by instruction, so its table joins a trace to
+the model's layers.
 """
 
 from __future__ import annotations
 
 import re
 from collections import defaultdict
+
+from repro.scopes import SCOPES
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -48,6 +55,7 @@ _CONST_RE = re.compile(r"\bconstant\((\d+)\)")
 _PAIRS_RE = re.compile(r"source_target_pairs=\{((?:\{\d+,\d+\},?)*)\}")
 _GROUPS_BRACKET_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 _GROUPS_BRACE_RE = re.compile(r"replica_groups=\{\{([0-9, ]*)\}")
+_OP_NAME_RE = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
 
 COLLECTIVE_KINDS = (
     "all-gather", "all-reduce", "reduce-scatter", "all-to-all",
@@ -74,7 +82,7 @@ def _shape_dims(text: str) -> list[list[int]]:
 
 
 class Op:
-    __slots__ = ("name", "kind", "result", "line", "operands", "comps")
+    __slots__ = ("name", "kind", "result", "line", "operands", "comps", "op_name")
 
     def __init__(self, name, kind, result, line):
         self.name = name
@@ -83,6 +91,7 @@ class Op:
         self.line = line  # attrs text (post-operands, pre-metadata)
         self.operands: list[str] = []
         self.comps: list[str] = []
+        self.op_name: str | None = None  # metadata op_name (None: XLA made it)
 
 
 class Computation:
@@ -134,6 +143,9 @@ def parse_module(hlo_text: str) -> dict[str, Computation]:
         bm = _BRANCHES_RE.search(attrs)
         if bm:
             op.comps += _REF_RE.findall(bm.group(1))
+        nm = _OP_NAME_RE.search(raw)
+        if nm:
+            op.op_name = nm.group(1)
         cur.defs[name] = result
         cur.ops.append(op)
     return comps
@@ -372,3 +384,123 @@ def parse_collectives(hlo_text: str, *, n_devices: int | None = None) -> dict:
     if "permute_pair_fraction" in a:
         out["permute_pair_fraction"] = a["permute_pair_fraction"]
     return out
+
+
+# ----------------------------------------------------------- named scopes
+_SCOPE_PARTS = [(path, path.split("/")) for path in SCOPES]
+_WRAPPED_RE = re.compile(r"^(?:[\w\-]+\()+([^()]*)\)+$")  # transpose(jvp(moe)) -> moe
+# ops that run the instructions of other computations
+_CONTROL_OPS = ("while", "conditional", "call")
+# ops that a trace does not show as device work
+TRIVIAL_OPS = frozenset(
+    {"parameter", "constant", "get-tuple-element", "tuple", "bitcast", *_CONTROL_OPS}
+)
+_PASS_THROUGH = ("tuple", "get-tuple-element", "bitcast", "copy")
+_UNNAMED = object()  # an instruction XLA made: no op_name
+
+
+def scope_of(op_name: str) -> str | None:
+    """The innermost ``SCOPES`` path in an ``op_name`` (None if none)."""
+    parts = [_WRAPPED_RE.sub(r"\1", p) for p in op_name.split("/")]
+    best, best_end = None, -1
+    for path, want in _SCOPE_PARTS:
+        k = len(want)
+        for i in range(len(parts) - k, -1, -1):
+            if parts[i : i + k] == want:
+                if i + k > best_end:
+                    best, best_end = path, i + k
+                break
+    return best
+
+
+def _run_by_control_flow(comps: dict[str, Computation]) -> dict[str, Op | None]:
+    """The computations whose instructions run as device ops of their
+    own: the entry, and those that control flow runs (loop bodies and
+    conditions, branches, calls), not the insides of fusions or
+    reducers; each with the instruction that runs it, parents first."""
+    entry = next((c for c in comps.values() if c.is_entry), None)
+    order: dict[str, Op | None] = {}
+    todo = [(entry.name, None)] if entry is not None else []
+    while todo:
+        name, caller = todo.pop(0)
+        if name in order or name not in comps:
+            continue
+        order[name] = caller
+        for op in comps[name].ops:
+            if op.kind in _CONTROL_OPS or op.kind.endswith("-start"):
+                todo.extend((c, op) for c in op.comps)
+    return order
+
+
+def trace_ops(hlo_text: str) -> list[str]:
+    """Names of the instructions a profiler trace shows as device work."""
+    comps = parse_module(hlo_text)
+    return [
+        op.name
+        for c in _run_by_control_flow(comps)
+        for op in comps[c].ops
+        if op.kind not in TRIVIAL_OPS
+    ]
+
+
+def op_scopes(hlo_text: str) -> dict[str, str]:
+    """{instruction name: innermost ``SCOPES`` path} over the
+    instructions of the computations a trace shows (see
+    ``trace_ops``).  An instruction whose ``op_name`` names no scope is
+    left out.  One that XLA made (no ``op_name``) takes the scope of what
+    is inside it (a fusion), else of its nearest user or operand
+    (through tuples and copies), else of the instruction that runs its
+    computation."""
+    comps = parse_module(hlo_text)
+    out: dict[str, str] = {}
+    for name, caller in _run_by_control_flow(comps).items():
+        outer = out.get(caller.name) if caller is not None else None
+        comp = comps[name]
+        own = {op.name: _own_scope(op, comps) for op in comp.ops}
+        ops = {op.name: op for op in comp.ops}
+        users: dict[str, list[str]] = defaultdict(list)
+        for op in comp.ops:
+            for a in op.operands:
+                users[a].append(op.name)
+        for op in comp.ops:
+            sc = own[op.name]
+            if sc is _UNNAMED:
+                sc = (
+                    _nearest(op.name, lambda n: users.get(n, ()), ops, own)
+                    or _nearest(op.name, lambda n: ops[n].operands if n in ops else (), ops, own)
+                    or outer
+                )
+            if sc is not None:
+                out[op.name] = sc
+    return out
+
+
+def _own_scope(op: Op, comps: dict[str, Computation]):
+    if op.op_name is not None:
+        return scope_of(op.op_name)
+    if op.kind == "fusion":
+        for c in op.comps:
+            for inner in reversed(comps[c].ops) if c in comps else ():
+                if inner.op_name is not None:
+                    return scope_of(inner.op_name)
+    return _UNNAMED
+
+
+def _nearest(name: str, step, ops: dict, own: dict) -> str | None:
+    """The scope of the nearest instruction along ``step`` (users or
+    operands) that has one, through tuples and copies, within 3 steps."""
+    frontier, seen = [name], {name}
+    for _ in range(3):
+        nxt = []
+        for n in frontier:
+            for m in step(n):
+                if m in seen:
+                    continue
+                seen.add(m)
+                sc = own.get(m)
+                if isinstance(sc, str):
+                    return sc
+                if m in ops and ops[m].kind in _PASS_THROUGH:
+                    nxt.append(m)
+        frontier = nxt
+    return None

@@ -1,3 +1,4 @@
 from repro.models.model import Model
+from repro.scopes import SCOPES
 
-__all__ = ["Model"]
+__all__ = ["Model", "SCOPES"]

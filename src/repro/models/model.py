@@ -29,6 +29,7 @@ from repro.models.layers import (
     unembed_apply,
 )
 from repro.parallel import shard
+from repro.scopes import scope
 
 
 class Model:
@@ -83,21 +84,23 @@ class Model:
     # ------------------------------------------------------------ forward
     def _embed(self, params, tokens, ext_embeds=None, *, offset=0):
         cfg = self.cfg
-        x = embed_apply(params["embed"], tokens)
-        if ext_embeds is not None:
-            x = jnp.concatenate([cast(ext_embeds), x], axis=1)
-        if cfg.pos_embedding == "sinusoidal":
-            x = x + sinusoidal_pos(x.shape[1], cfg.d_model, offset=offset)[None]
-        return shard(x, "batch", None, "embed")
+        with scope("embed"):
+            x = embed_apply(params["embed"], tokens)
+            if ext_embeds is not None:
+                x = jnp.concatenate([cast(ext_embeds), x], axis=1)
+            if cfg.pos_embedding == "sinusoidal":
+                x = x + sinusoidal_pos(x.shape[1], cfg.d_model, offset=offset)[None]
+            return shard(x, "batch", None, "embed")
 
     def _logits(self, params, x):
         cfg = self.cfg
-        x = rmsnorm_apply(params["ln_f"], x, eps=cfg.norm_eps)
-        if cfg.tie_embeddings:
-            logits = unembed_apply(params["embed"], x)
-        else:
-            logits = dense_apply(params["head"], x).astype(jnp.float32)
-        return shard(logits, "batch", None, "vocab")
+        with scope("logits"):
+            x = rmsnorm_apply(params["ln_f"], x, eps=cfg.norm_eps)
+            if cfg.tie_embeddings:
+                logits = unembed_apply(params["embed"], x)
+            else:
+                logits = dense_apply(params["head"], x).astype(jnp.float32)
+            return shard(logits, "batch", None, "vocab")
 
     def forward(self, params, tokens, ext_embeds=None, *, schedule=None):
         """Training/eval forward: full-sequence logits [B, S, V] (f32)."""
@@ -203,19 +206,20 @@ class Model:
         a static-shape decode batch never register as expert demand."""
         cfg = self.cfg
         step = jnp.asarray(step, jnp.int32)
-        x = embed_apply(params["embed"], token[:, None])
-        if cfg.pos_embedding == "sinusoidal":
-            if step.ndim == 1:
-                pe = jax.vmap(
-                    lambda o: sinusoidal_pos(1, cfg.d_model, offset=o)
-                )(step)  # [B, 1, d]
-                x = x + pe
-            else:
-                x = x + sinusoidal_pos(1, cfg.d_model, offset=step)[None]
-        x = shard(x, "batch", None, "embed")
-        token_weight = (
-            None if live is None else live.astype(jnp.float32)[:, None]
-        )
+        with scope("embed"):
+            x = embed_apply(params["embed"], token[:, None])
+            if cfg.pos_embedding == "sinusoidal":
+                if step.ndim == 1:
+                    pe = jax.vmap(
+                        lambda o: sinusoidal_pos(1, cfg.d_model, offset=o)
+                    )(step)  # [B, 1, d]
+                    x = x + pe
+                else:
+                    x = x + sinusoidal_pos(1, cfg.d_model, offset=step)[None]
+            x = shard(x, "batch", None, "embed")
+            token_weight = (  # the stack's other input
+                None if live is None else live.astype(jnp.float32)[:, None]
+            )
         out = stack.stack_decode(
             params["stack"], cfg, x, caches, step, self._sched(schedule),
             collect_stats=collect_stats, token_weight=token_weight,

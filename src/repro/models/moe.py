@@ -47,6 +47,7 @@ from repro.parallel.fabric.base import (
     resolve_fabric,
 )
 from repro.models.layers import cast, dense_init
+from repro.scopes import scope
 
 EP_AXIS = "model"
 
@@ -169,16 +170,21 @@ def _pipeline_body(
     slots never count as demand."""
     m = ctx.cfg.moe
     t = x_loc.shape[0]
-    idx, gates = _router({"router": {"w": wr}}, ctx.cfg, x_loc)
-    packed = fabric.pack(ctx, x_loc, idx, gates)
-    # wire codec: quantize the wire-crossing slots on both fabric legs
-    # (bf16 passthrough is the identity — see fabric.codec)
-    packed = fabric.wire_encode(ctx, packed)
-    blocks, state = fabric.dispatch(ctx, packed)
-    ys = [_expert_block(ctx, wg, wu, wd, blk, live) for blk, live in blocks]
-    y_slots = fabric.combine(ctx, packed, state, ys)
-    y_slots = fabric.wire_decode(ctx, packed, y_slots)
-    y_loc = _ungroup(y_slots, packed.pos, packed.gate, t)  # [t, d] f32
+    with scope("moe/router"):
+        idx, gates = _router({"router": {"w": wr}}, ctx.cfg, x_loc)
+    with scope("moe/pack"):
+        packed = fabric.pack(ctx, x_loc, idx, gates)
+        # wire codec: quantize the wire-crossing slots on both fabric legs
+        # (bf16 passthrough is the identity — see fabric.codec)
+        packed = fabric.wire_encode(ctx, packed)
+    with scope("moe/dispatch"):
+        blocks, state = fabric.dispatch(ctx, packed)
+    with scope("moe/expert_ffn"):
+        ys = [_expert_block(ctx, wg, wu, wd, blk, live) for blk, live in blocks]
+    with scope("moe/combine"):
+        y_slots = fabric.combine(ctx, packed, state, ys)
+        y_slots = fabric.wire_decode(ctx, packed, y_slots)
+        y_loc = _ungroup(y_slots, packed.pos, packed.gate, t)  # [t, d] f32
     if not return_stats:
         return y_loc
     counts = _routing_counts(idx, m.n_experts, weight=token_weight)

@@ -34,6 +34,16 @@ from repro.models.layers import (
     swiglu_init,
 )
 from repro.models.moe import moe_apply, moe_init
+from repro.scopes import scope
+
+# each block's mixer sublayer (its norm and residual too) runs under its
+# kind's scope, and its FFN sublayer under "moe" or "ffn"; the layer scan
+# runs under "stack", so what the scan itself does is under no layer
+MIXER_SCOPES = {"attn": "attention", "mamba": "mamba", "rwkv6": "rwkv"}
+
+
+def _ffn_scope(cfg: ModelConfig, j: int) -> str:
+    return "moe" if cfg.ffn_kind(j) == "moe" else "ffn"
 
 
 # ----------------------------------------------------------- single block
@@ -98,21 +108,23 @@ def block_train(p, cfg: ModelConfig, j: int, x, schedule, *, collect_stats=False
         return shard(t, "batch", "seq_act", "embed")
 
     kind = cfg.layer_kind(j)
-    h = rmsnorm_apply(p["ln1"], x, eps=cfg.norm_eps)
-    if kind == "attn":
-        x = seq_sharded(x + attn.attn_train(p["mixer"], cfg, h))
-    elif kind == "mamba":
-        y, _ = mb.mamba_seq(p["mixer"], cfg, h)
-        x = seq_sharded(x + y)
-    else:  # rwkv6
-        y, _ = rk.rwkv_time_mix(p["mixer"], cfg, h)
-        x = seq_sharded(x + y)
-        h2 = rmsnorm_apply(p["ln2"], x, eps=cfg.norm_eps)
-        y2, _ = rk.rwkv_channel_mix(p["mixer"], h2)
-        return seq_sharded(x + y2), None
-    h = rmsnorm_apply(p["ln2"], x, eps=cfg.norm_eps)
-    y, stats = _ffn_apply(p, cfg, j, h, schedule, collect_stats)
-    return seq_sharded(x + y), stats
+    with scope(MIXER_SCOPES[kind]):
+        h = rmsnorm_apply(p["ln1"], x, eps=cfg.norm_eps)
+        if kind == "attn":
+            x = seq_sharded(x + attn.attn_train(p["mixer"], cfg, h))
+        elif kind == "mamba":
+            y, _ = mb.mamba_seq(p["mixer"], cfg, h)
+            x = seq_sharded(x + y)
+        else:  # rwkv6
+            y, _ = rk.rwkv_time_mix(p["mixer"], cfg, h)
+            x = seq_sharded(x + y)
+            h2 = rmsnorm_apply(p["ln2"], x, eps=cfg.norm_eps)
+            y2, _ = rk.rwkv_channel_mix(p["mixer"], h2)
+            return seq_sharded(x + y2), None
+    with scope(_ffn_scope(cfg, j)):
+        h = rmsnorm_apply(p["ln2"], x, eps=cfg.norm_eps)
+        y, stats = _ffn_apply(p, cfg, j, h, schedule, collect_stats)
+        return seq_sharded(x + y), stats
 
 
 def block_cache(cfg: ModelConfig, j: int, batch: int, max_len: int, dtype=jnp.bfloat16):
@@ -127,24 +139,26 @@ def block_cache(cfg: ModelConfig, j: int, batch: int, max_len: int, dtype=jnp.bf
 
 def block_prefill(p, cfg, j, x, cache, schedule):
     kind = cfg.layer_kind(j)
-    h = rmsnorm_apply(p["ln1"], x, eps=cfg.norm_eps)
-    if kind == "attn":
-        y, cache = attn.attn_prefill(p["mixer"], cfg, h, cache)
-        x = x + y
-    elif kind == "mamba":
-        y, (hs, tail) = mb.mamba_seq(p["mixer"], cfg, h)
-        cache = (hs, tail.astype(cache[1].dtype))
-        x = x + y
-    else:  # rwkv6
-        y, (x_tm, s) = rk.rwkv_time_mix(p["mixer"], cfg, h)
-        x = x + y
-        h2 = rmsnorm_apply(p["ln2"], x, eps=cfg.norm_eps)
-        y2, x_cm = rk.rwkv_channel_mix(p["mixer"], h2)
-        x = x + y2
-        return x, (x_tm.astype(cache[0].dtype), s, x_cm.astype(cache[2].dtype))
-    h = rmsnorm_apply(p["ln2"], x, eps=cfg.norm_eps)
-    x = x + _ffn_apply(p, cfg, j, h, schedule)[0]
-    return x, cache
+    with scope(MIXER_SCOPES[kind]):
+        h = rmsnorm_apply(p["ln1"], x, eps=cfg.norm_eps)
+        if kind == "attn":
+            y, cache = attn.attn_prefill(p["mixer"], cfg, h, cache)
+            x = x + y
+        elif kind == "mamba":
+            y, (hs, tail) = mb.mamba_seq(p["mixer"], cfg, h)
+            cache = (hs, tail.astype(cache[1].dtype))
+            x = x + y
+        else:  # rwkv6
+            y, (x_tm, s) = rk.rwkv_time_mix(p["mixer"], cfg, h)
+            x = x + y
+            h2 = rmsnorm_apply(p["ln2"], x, eps=cfg.norm_eps)
+            y2, x_cm = rk.rwkv_channel_mix(p["mixer"], h2)
+            x = x + y2
+            return x, (x_tm.astype(cache[0].dtype), s, x_cm.astype(cache[2].dtype))
+    with scope(_ffn_scope(cfg, j)):
+        h = rmsnorm_apply(p["ln2"], x, eps=cfg.norm_eps)
+        x = x + _ffn_apply(p, cfg, j, h, schedule)[0]
+        return x, cache
 
 
 def block_decode(
@@ -157,32 +171,34 @@ def block_decode(
     channel-mix) — the serving engine's observation signal, weighted by
     the slot-liveness mask ``token_weight``."""
     kind = cfg.layer_kind(j)
-    h = rmsnorm_apply(p["ln1"], x, eps=cfg.norm_eps)
-    if kind == "attn":
-        y, cache = attn.attn_decode(p["mixer"], cfg, h, cache, step)
-        x = x + y
-    elif kind == "mamba":
-        y, cache = mb.mamba_step(p["mixer"], cfg, h, cache)
-        x = x + y
-    else:  # rwkv6
-        x_tm, s, x_cm = cache
-        y, (x_tm2, s2) = rk.rwkv_time_mix(
-            p["mixer"], cfg, h, state=(x_tm.astype(h.dtype), s)
+    with scope(MIXER_SCOPES[kind]):
+        h = rmsnorm_apply(p["ln1"], x, eps=cfg.norm_eps)
+        if kind == "attn":
+            y, cache = attn.attn_decode(p["mixer"], cfg, h, cache, step)
+            x = x + y
+        elif kind == "mamba":
+            y, cache = mb.mamba_step(p["mixer"], cfg, h, cache)
+            x = x + y
+        else:  # rwkv6
+            x_tm, s, x_cm = cache
+            y, (x_tm2, s2) = rk.rwkv_time_mix(
+                p["mixer"], cfg, h, state=(x_tm.astype(h.dtype), s)
+            )
+            x = x + y
+            h2 = rmsnorm_apply(p["ln2"], x, eps=cfg.norm_eps)
+            y2, x_cm2 = rk.rwkv_channel_mix(
+                p["mixer"], h2, state=x_cm.astype(h2.dtype)
+            )
+            x = x + y2
+            cache = (x_tm2.astype(x_tm.dtype), s2, x_cm2.astype(x_cm.dtype))
+            return (x, cache, None) if collect_stats else (x, cache)
+    with scope(_ffn_scope(cfg, j)):
+        h = rmsnorm_apply(p["ln2"], x, eps=cfg.norm_eps)
+        y, stats = _ffn_apply(
+            p, cfg, j, h, schedule, collect_stats, token_weight
         )
         x = x + y
-        h2 = rmsnorm_apply(p["ln2"], x, eps=cfg.norm_eps)
-        y2, x_cm2 = rk.rwkv_channel_mix(
-            p["mixer"], h2, state=x_cm.astype(h2.dtype)
-        )
-        x = x + y2
-        cache = (x_tm2.astype(x_tm.dtype), s2, x_cm2.astype(x_cm.dtype))
-        return (x, cache, None) if collect_stats else (x, cache)
-    h = rmsnorm_apply(p["ln2"], x, eps=cfg.norm_eps)
-    y, stats = _ffn_apply(
-        p, cfg, j, h, schedule, collect_stats, token_weight
-    )
-    x = x + y
-    return (x, cache, stats) if collect_stats else (x, cache)
+        return (x, cache, stats) if collect_stats else (x, cache)
 
 
 # ------------------------------------------------------------------ stack
@@ -312,7 +328,8 @@ def stack_train(
         out, stats = period_fn(carry, pparams, prow)
         return shard(out, "batch", "seq_act", "embed"), stats
 
-    x, stats = jax.lax.scan(scan_fn, x, (params, rows))
+    with scope("stack"):
+        x, stats = jax.lax.scan(scan_fn, x, (params, rows))
     if not collect_stats:
         return x
     # stats: tuple (per MoE period position) of stat pytrees with leading
@@ -341,7 +358,8 @@ def stack_prefill(params, cfg: ModelConfig, x, caches, schedule):
             new[f"pos{j}"] = c
         return carry, new
 
-    x, caches = jax.lax.scan(scan_fn, x, (params, caches, rows))
+    with scope("stack"):
+        x, caches = jax.lax.scan(scan_fn, x, (params, caches, rows))
     return x, caches
 
 
@@ -381,7 +399,8 @@ def stack_decode(
             new[f"pos{j}"] = c
         return carry, (new, tuple(stats))
 
-    x, (caches, stats) = jax.lax.scan(scan_fn, x, (params, caches, rows))
+    with scope("stack"):
+        x, (caches, stats) = jax.lax.scan(scan_fn, x, (params, caches, rows))
     if not collect_stats:
         return x, caches
     # stats: tuple (per MoE period position) of stat pytrees with leading

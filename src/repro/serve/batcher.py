@@ -15,6 +15,7 @@ never reads their outputs.
 from __future__ import annotations
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serve.queue import Request
 
@@ -65,23 +66,25 @@ class ContinuousBatcher:
     def advance(self, next_tokens: np.ndarray, wall: float) -> list[Request]:
         """Fold one decode step's outputs: append each live slot's token,
         bump its position, and vacate slots that hit their budget.
-        Returns the finished requests (already vacated)."""
-        next_tokens = np.asarray(next_tokens)
-        finished: list[Request] = []
-        for s in np.flatnonzero(self.live):
-            req = self.requests[s]
-            tok = int(next_tokens[s])
-            if not req.tokens:
-                req.first_token_wall = wall
-            req.tokens.append(tok)
-            self.token[s] = tok
-            self.step[s] += 1
-            self.remaining[s] -= 1
-            if self.remaining[s] == 0:
-                req.finish_wall = wall
-                finished.append(req)
-                self.vacate(s)
-        return finished
+        Returns the finished requests (already vacated).  Runs under the
+        ``serve.engine.advance`` span."""
+        with TraceAnnotation("serve.engine.advance"):
+            next_tokens = np.asarray(next_tokens)
+            finished: list[Request] = []
+            for s in np.flatnonzero(self.live):
+                req = self.requests[s]
+                tok = int(next_tokens[s])
+                if not req.tokens:
+                    req.first_token_wall = wall
+                req.tokens.append(tok)
+                self.token[s] = tok
+                self.step[s] += 1
+                self.remaining[s] -= 1
+                if self.remaining[s] == 0:
+                    req.finish_wall = wall
+                    finished.append(req)
+                    self.vacate(s)
+            return finished
 
     def vacate(self, slot: int) -> None:
         self.requests[slot] = None

@@ -27,6 +27,16 @@ Admission is KV-aware: a request whose peak position exceeds the
 decode cache is rejected at enqueue (surfaced in metrics), and one that
 fits but finds no free slot waits in the length-bucketed queue.
 
+**Spans.**  The host's part of serving runs under profiler annotations
+on the trace's own clock (free while no profiler runs), so a trace puts
+each device-idle gap down to host work: ``serve.engine.admit`` per
+request (``rid``, ``bucket``, ``slot``), and per decode step (``step``)
+``serve.engine.decode.inputs`` (copies in), ``.launch`` (the jit call),
+``.fetch`` (the wait for the next tokens), ``serve.engine.observe`` (the
+host planner) and ``serve.engine.advance`` (the batcher).  Each program
+the engine runs is noted in ``repro.serve.metrics`` (shapes only), so a
+trace's ops can be put down to the model's scopes.
+
 **Schedule-regime warm-swap.**  With ``regime_slots > 0`` the device
 controller state carries a library of pre-planned tables keyed by
 normalized traffic shape.  ``capture_regime`` snapshots the *current*
@@ -47,11 +57,13 @@ from collections import deque
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.launch.rules import dtype_policy
 from repro.models import Model
+from repro.scopes import scope
 from repro.serve.batcher import ContinuousBatcher
-from repro.serve.metrics import ServeMetrics
+from repro.serve.metrics import ServeMetrics, register_program
 from repro.serve.queue import Request, RequestQueue
 
 __all__ = ["ServeEngine", "init_serve_params"]
@@ -119,6 +131,7 @@ class ServeEngine:
         self._metrics.n_slots = decode_slots
         self._host_swaps = 0
         self._routing_acc: list[np.ndarray] = []
+        self._decode_steps = 0
         self._bank_tables: list = []
         self._bank_refs: list[np.ndarray] = []
 
@@ -156,7 +169,8 @@ class ServeEngine:
                     params, token, caches, steps, schedule=table,
                     collect_stats=True, live=live,
                 )
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                with scope("logits"):
+                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 state = ctrl.step(state, stats["routing"], stats["dropped"])
                 return nxt, caches, state, stats["routing"]
 
@@ -165,9 +179,12 @@ class ServeEngine:
             def _decode(params, token, caches, steps, live):
                 del live  # liveness only weights stats; none collected
                 logits, caches = model.decode_step(params, token, caches, steps)
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32), caches
+                with scope("logits"):
+                    return jnp.argmax(logits, axis=-1).astype(jnp.int32), caches
 
-        self._decode = jax.jit(_decode)
+        # the runner of a benchmark may wrap ``_decode``; the table of
+        # programs needs the jitted function itself
+        self._decode = self._decode_jit = jax.jit(_decode)
 
         def _admit(caches, row, slot, plen):
             # padding KV written by the bucketed prefill carries positions
@@ -330,28 +347,29 @@ class ServeEngine:
         if plen > 0:
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :plen] = req.prompt[:-1]
-            _, row = self._prefill(
-                self.params, jnp.asarray(padded), row,
-                schedule=self._prefill_table,
+            args = (self.params, jnp.asarray(padded), row)
+            register_program(
+                self._prefill, *args, variant=bucket, schedule=self._prefill_table
             )
+            _, row = self._prefill(*args, schedule=self._prefill_table)
         return row, plen
 
     def _admit_ready(self, step_no: int, wall: float) -> None:
         """Admit queued requests into free slots (KV already checked at
         enqueue: anything in the queue fits a slot's cache)."""
-        while True:
+        while len(self.queue):
             slot = self.batcher.free_slot()
             if slot is None:
                 return
-            item = self.queue.pop()
-            if item is None:
-                return
-            req, bucket = item
-            row, plen = self._prefill_row(req, bucket)
-            self._caches = self._admit_jit(
-                self._caches, row, jnp.int32(slot), jnp.int32(plen)
-            )
-            self.batcher.admit(slot, req)
+            with TraceAnnotation("serve.engine.admit") as span:
+                req, bucket = self.queue.pop()
+                span.set_metadata(rid=req.rid, bucket=bucket, slot=slot)
+                row, plen = self._prefill_row(req, bucket)
+                args = (self._caches, row, jnp.int32(slot), jnp.int32(plen))
+                register_program(self._admit_jit, *args)
+                self._caches = self._admit_jit(*args)
+                del args  # the old cache, before the next prefill
+                self.batcher.admit(slot, req)
             req.admit_step = step_no
             req.admit_wall = wall
             self._metrics.record_admitted(req, step_no)
@@ -359,21 +377,30 @@ class ServeEngine:
     def _decode_once(self) -> np.ndarray:
         """One fused decode step over the slot batch; returns the next
         token per slot (garbage on vacant slots — never read)."""
-        token = jnp.asarray(self.batcher.token)
-        steps = jnp.asarray(self.batcher.step)
-        live = jnp.asarray(self.batcher.live)
+        n = self._decode_steps
+        self._decode_steps += 1
+        with TraceAnnotation("serve.engine.decode.inputs", step=n):
+            token = jnp.asarray(self.batcher.token)
+            steps = jnp.asarray(self.batcher.step)
+            live = jnp.asarray(self.batcher.live)
+        state = () if self._ctrl is None else (self._state,)
+        register_program(
+            self._decode_jit, self.params, token, self._caches, steps, live, *state
+        )
+        with TraceAnnotation("serve.engine.decode.launch", step=n):
+            out = self._decode(self.params, token, self._caches, steps, live, *state)
         if self._ctrl is not None:
-            nxt, self._caches, self._state, routing = self._decode(
-                self.params, token, self._caches, steps, live, self._state
-            )
-            self._routing_acc.append(np.asarray(routing))
+            nxt, self._caches, self._state, routing = out
+            with TraceAnnotation("serve.engine.decode.fetch", step=n):
+                self._routing_acc.append(np.asarray(routing))
+                nxt = np.asarray(nxt)
             if len(self._routing_acc) >= self.host_observe_every:
-                self._host_observe()
-        else:
-            nxt, self._caches = self._decode(
-                self.params, token, self._caches, steps, live
-            )
-        return np.asarray(nxt)
+                with TraceAnnotation("serve.engine.observe", step=n):
+                    self._host_observe()
+            return nxt
+        nxt, self._caches = out
+        with TraceAnnotation("serve.engine.decode.fetch", step=n):
+            return np.asarray(nxt)
 
     def _host_observe(self) -> None:
         """Feed aggregated realized decode routing to the host planner —

@@ -1,20 +1,29 @@
 """Serving telemetry: admission counters, queue waits, and latency /
-throughput percentiles.
+throughput percentiles; and the table of the engine's device programs,
+from which a profiler trace's ops are put down to the model's scopes.
 
-Everything here is host-side bookkeeping over completed lifecycle
+``ServeMetrics`` is host-side bookkeeping over completed lifecycle
 events; nothing touches the device.  Queue waits are recorded in
-*virtual* decode-step units (deterministic under any host speed) and
-converted to wall milliseconds in ``summary`` via the measured mean
-step duration; per-request throughput uses real wall timestamps.
+*virtual* decode-step units (deterministic under any host speed);
+per-request throughput uses real wall timestamps.
+
+The engine notes each program it runs (``register_program``: the jitted
+function, weakly, and its arguments' shapes, dtypes and shardings, no
+buffer).  ``op_scopes(module)`` compiles the program for those
+arguments when first asked, and reads its instructions' scopes
+(``repro.launch.hlo.op_scopes``); an untraced run never asks.
 """
 
 from __future__ import annotations
 
+import weakref
+
+import jax
 import numpy as np
 
 from repro.serve.queue import Request
 
-__all__ = ["ServeMetrics", "percentiles"]
+__all__ = ["ServeMetrics", "op_scopes", "percentiles", "programs", "register_program"]
 
 
 def percentiles(xs, ps=(50, 99)) -> dict:
@@ -74,8 +83,6 @@ class ServeMetrics:
 
     # ------------------------------------------------------------ summary
     def summary(self) -> dict:
-        step_s = self.wall_s / max(self.decode_steps, 1)
-        wait = percentiles(self.queue_wait_steps)
         return {
             "requests": {
                 "offered": self.offered,
@@ -83,17 +90,75 @@ class ServeMetrics:
                 "rejected": self.rejected,
                 "completed": self.completed,
             },
-            "queue_wait_steps": wait,
-            "queue_wait_ms": {
-                k: v * step_s * 1e3 for k, v in wait.items()
-            },
+            "queue_wait_steps": percentiles(self.queue_wait_steps),
             "request_tok_s": percentiles(self.request_tok_s),
             "request_latency_s": percentiles(self.request_latency_s),
             "throughput_tok_s": self.generated_tokens / max(self.wall_s, 1e-9),
             "generated_tokens": self.generated_tokens,
             "decode_steps": self.decode_steps,
             "idle_steps": self.idle_steps,
-            "step_ms": step_s * 1e3,
             "occupancy": self.live_slot_steps
             / max(self.decode_steps * max(self.n_slots, 1), 1),
         }
+
+
+# ------------------------------------------------------------- programs
+class _Program:
+    __slots__ = ("fn", "args", "kwargs", "table")
+
+    def __init__(self, fn, args, kwargs):
+        self.fn = weakref.ref(fn)
+        self.args, self.kwargs = args, kwargs
+        self.table: dict[str, str] | None = None
+
+
+# (module name, variant) -> the program last run under that name
+_PROGRAMS: dict[tuple[str, object], _Program] = {}
+
+
+def _spec(a):
+    if isinstance(a, jax.Array):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding, weak_type=a.weak_type)
+    if isinstance(a, np.ndarray):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype)
+    return a
+
+
+def register_program(fn, *args, variant=None, **kwargs) -> None:
+    """Note that the jitted ``fn`` runs with these arguments, under the
+    module name its trace events carry (``jit_<name>``).  ``variant``
+    tells apart executables of one function (a prefill bucket).  Costs a
+    dict lookup once noted."""
+    key = (f"jit_{fn.__name__}", variant)
+    prog = _PROGRAMS.get(key)
+    if prog is not None and prog.fn() is fn:
+        return
+    args, kwargs = jax.tree.map(_spec, (args, kwargs))
+    _PROGRAMS[key] = _Program(fn, args, kwargs)
+
+
+def programs() -> list[tuple[str, object]]:
+    """(module, variant) of every noted program whose function lives."""
+    return [k for k, p in _PROGRAMS.items() if p.fn() is not None]
+
+
+def op_scopes(module: str, variant=None) -> dict[str, str]:
+    """{instruction: scope} of the live program noted under ``module``
+    (``repro.launch.hlo.op_scopes`` of its compiled text), built on the
+    first call.  Raises KeyError if there is none, ValueError if
+    ``variant`` is needed to choose among several."""
+    keys = [k for k in programs() if k[0] == module and (variant is None or k[1] == variant)]
+    if not keys:
+        raise KeyError(f"no live program {module!r} (variant {variant!r})")
+    if len(keys) > 1:
+        raise ValueError(f"{module!r} names {len(keys)} executables; give a variant: {keys}")
+    prog = _PROGRAMS[keys[0]]
+    if prog.table is None:
+        from repro.launch.hlo import op_scopes as hlo_op_scopes
+
+        text = prog.fn().lower(*prog.args, **prog.kwargs).compile().as_text()
+        head = text.split(",", 1)[0].split()
+        if head[:2] != ["HloModule", module]:
+            raise ValueError(f"compiled {head[1:2]}, not {module!r}")
+        prog.table = hlo_op_scopes(text)
+    return prog.table
