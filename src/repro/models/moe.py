@@ -26,6 +26,11 @@ the wrong schedule flavor raises naming the backend that rejected it.
 
 Routing: top-k softmax gating with capacity-factor token dropping
 (GShard-style), gates optionally renormalized over the selected k.
+
+A layer that is dropless by shape and has enough rows per expert skips
+the padded ``[E, cap, d]`` buckets: its ``t * k`` routed choices
+are sorted by expert and the SwiGLU runs as one grouped GEMM over
+exactly those rows (``_sorted_body``; the rule is ``_sorted_path``).
 Token-slot geometry (packing, admission, phase-slot math) is shared by
 every backend — see ``repro.parallel.fabric.geometry``; this module
 re-exports the old underscore names for its tests.
@@ -33,8 +38,11 @@ re-exports the old underscore names for its tests.
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import gmm
 
 from repro.configs.base import ModelConfig
 from repro.core.hierarchical import HierarchicalTable
@@ -46,7 +54,7 @@ from repro.parallel.fabric.base import (
     get_fabric,
     resolve_fabric,
 )
-from repro.models.layers import cast, dense_init
+from repro.models.layers import COMPUTE_DTYPE, cast, dense_init
 from repro.scopes import scope
 
 EP_AXIS = "model"
@@ -65,6 +73,25 @@ _phase_serving = _geom.phase_serving
 _phase_slot_assign = _geom.phase_slot_assign
 _routing_counts = _geom.routing_counts
 _stats = _geom.stats_tree
+
+# Rows per expert from which the sorted pipeline beats the padded einsum
+# on TPU v5e.  By the roofline alone it would be about 240: a bf16 GEMM
+# that streams an expert's weights does ``rows`` FLOP per weight byte,
+# and the ridge is 197e12 FLOP/s / 819e9 B/s; below it the weight read
+# sets the time and the padding rows are free.  Measured at Mixtral
+# widths (``benchmarks/expert_gemm_sweep.py``, the whole layer, cap = t,
+# the sorted GEMMs by ``gmm`` at ``GMM_TILING``): padded / sorted 4.78 /
+# 5.14 ms at 128 rows per expert, 5.41 / 5.73 at 256, 9.66 / 6.98 at
+# 512, 19.65 / 9.32 at 1024.  The grouped GEMM costs a little more than
+# the einsum's weight read, so the bound is the measured crossing.
+ROWS_COMPUTE_BOUND = 512
+
+# Row, contraction and output tiles of the sorted path's grouped GEMM.
+# The whole layer at Mixtral widths on v5e, 1024 / 2048 rows per
+# expert: ``jax.lax.ragged_dot`` 13.5 / 19.1 ms; ``gmm`` at 256/1024/1024
+# 9.3 / 14.9, at 512/1024/1024 12.2 / 16.3, at 128/1024/1024 12.1 /
+# 21.4, at 512/512/1024 12.7 / 17.2.
+GMM_TILING = (256, 1024, 1024)
 
 
 def moe_init(key: jax.Array, cfg: ModelConfig) -> dict:
@@ -154,7 +181,168 @@ def _expert_block(ctx: FabricContext, wg, wu, wd, blk, live):
     )
 
 
+def _grouped_matmul(x, w, group_sizes) -> jax.Array:
+    """Rows of ``x`` [N, k], grouped in order by ``group_sizes`` [G],
+    times their group's ``w`` [G, k, n] -> [N, n] in ``x``'s dtype, f32
+    accumulation (megablox ``gmm``; interpreted off the TPU).  ``gmm``
+    wants whole row tiles: N is padded to one and the padding (in no
+    group) cut off again."""
+    tm, tk, tn = GMM_TILING
+    n = x.shape[0]
+    tiling = (tm, min(tk, w.shape[1]), min(tn, w.shape[2]))
+    y = gmm(
+        jnp.pad(x, ((0, -n % tm), (0, 0))), w, group_sizes, x.dtype, tiling,
+        interpret=jax.default_backend() != "tpu",
+    )
+    return y[:n]
+
+
+def _expert_ffn_sorted(xs, group_sizes, wg, wu, wd, layer=None) -> jax.Array:
+    """The SwiGLU of ``_expert_ffn`` over rows sorted by expert.
+
+    xs: [N, d], rows of expert e contiguous and ``group_sizes[e]`` long;
+    one grouped GEMM per projection, so only the N routed rows are
+    multiplied.  Same operands, accumulation and f32 SiLU as the padded
+    einsum.
+
+    ``layer`` (traced int): the weights are a whole stack's, ``[L, E,
+    ...]`` (``StackExperts``), and the rows are layer ``layer``'s.  The
+    stack's ``L * E`` experts then form the groups, the other layers'
+    groups empty, so the GEMMs read the layer's weights in place."""
+    if layer is not None:
+        n_layers, e = wg.shape[:2]
+        wg, wu, wd = (w.reshape(n_layers * e, *w.shape[2:]) for w in (wg, wu, wd))
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_layers * e,), jnp.int32), group_sizes, (layer * e,)
+        )
+    g = _grouped_matmul(xs, cast(wg), group_sizes)
+    u = _grouped_matmul(xs, cast(wu), group_sizes)
+    h = jax.nn.silu(g.astype(jnp.float32)).astype(xs.dtype) * u
+    return _grouped_matmul(h, cast(wd), group_sizes)
+
+
+_EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+class StackExperts(NamedTuple):
+    """One MoE position's expert weights for a whole layer stack, ``[L,
+    E, ...]`` each, and the layer (traced int) that a call runs.  A
+    prefill's layer scan hands these to the sorted pipeline in place of
+    its per-layer slice: a grouped GEMM cannot fuse a scan's slice of
+    its weights, so the slice would copy them (``hold_stack_experts``)."""
+
+    w_gate: jax.Array
+    w_up: jax.Array
+    w_down: jax.Array
+    layer: jax.Array | None = None
+
+    def sliced(self) -> dict:
+        """The layer's own weights, as the padded pipeline takes them."""
+        return {k: w[self.layer] for k, w in zip(_EXPERT_WEIGHTS, self[:3])}
+
+
 # --------------------------------------------------------------- pipeline
+def _one_device() -> bool:
+    """No mesh shards this layer: its tokens and experts live on one
+    device (the dense fabric would shard its padded buckets otherwise)."""
+    ar = current_rules()
+    return ar is None or ar.mesh is None or ar.mesh.size == 1
+
+
+def _sorted_path(cfg: ModelConfig, t: int, row) -> bool:
+    """Does a ``t``-token layer run the sorted pipeline (else the padded
+    one)?  All of: (a) no schedule row — a row's admission masks and
+    wire semantics live in the fabric path — and no mesh; (b) not the
+    Pallas kernel's path; (c) dropless by shape: ``cap >= t``, and since
+    a token's k choices are distinct experts no bucket can overflow;
+    (d) enough rows per expert that the padding rows cost more than the
+    grouped GEMM's own overhead, ``cap >= ROWS_COMPUTE_BOUND``: below
+    that the padded einsum (a decode step's) stays as it is."""
+    m = cfg.moe
+    cap = _geom.bucket_capacity(t, m)
+    return (
+        row is None
+        and _one_device()
+        and not m.use_pallas
+        and cap >= t
+        and cap >= ROWS_COMPUTE_BOUND
+    )
+
+
+def _as_row(schedule):
+    """What the dense fabric makes of ``schedule``: a table or row stays
+    (its admission semantics run), anything else (None, a static
+    ``A2ASchedule``) is no row."""
+    return schedule if isinstance(schedule, (ScheduleTable, HierarchicalTable)) else None
+
+
+def expert_path(cfg: ModelConfig, t: int, schedule=None) -> str:
+    """``"sorted"`` or ``"padded"``: the expert pipeline a ``t``-token
+    MoE layer on one device takes when handed ``schedule``."""
+    return "sorted" if _sorted_path(cfg, t, _as_row(schedule)) else "padded"
+
+
+def expert_rows(cfg: ModelConfig, t: int, schedule=None) -> tuple[int, int]:
+    """(rows the expert GEMMs compute, routed rows) of one MoE layer of
+    ``t`` tokens on one device, by the rule that picks its path: the
+    sorted path computes exactly the ``t * k`` routed rows, the padded
+    one all ``E * cap`` bucket slots."""
+    m = cfg.moe
+    routed = t * m.top_k
+    if expert_path(cfg, t, schedule) == "sorted":
+        return routed, routed
+    return m.n_experts * _geom.bucket_capacity(t, m), routed
+
+
+def hold_stack_experts(params: dict, cfg: ModelConfig, t: int, schedule=None):
+    """Split a stack's expert weights off one MoE position's params
+    (``[L, ...]`` leaves, as a layer scan takes them) where its
+    ``t``-token layers run the sorted pipeline.
+
+    Returns (the params to scan, ``StackExperts`` or None).  Weights not
+    yet in the compute dtype stay in the scan: their cast copies each
+    layer's weights whatever the path, so holding them saves nothing."""
+    ws = tuple(params[k] for k in _EXPERT_WEIGHTS)
+    if expert_path(cfg, t, schedule) != "sorted" or any(
+        w.dtype != COMPUTE_DTYPE for w in ws
+    ):
+        return params, None
+    rest = {k: v for k, v in params.items() if k not in _EXPERT_WEIGHTS}
+    return rest, StackExperts(*ws)
+
+
+def _sorted_body(
+    ctx: FabricContext, x_loc, wr, wg, wu, wd, *, return_stats,
+    token_weight=None, layer=None,
+):
+    """The dropless pipeline of one device (``_sorted_path``): route ->
+    sort the ``t * k`` choices by expert -> one grouped GEMM over them ->
+    gate-weighted sum of each token's k rows.  Values and stats equal
+    ``_pipeline_body``'s on the dense fabric; nothing is dropped.
+    ``layer``: see ``_expert_ffn_sorted``."""
+    m = ctx.cfg.moe
+    t, d = x_loc.shape
+    tk = t * m.top_k
+    with scope("moe/router"):
+        idx, gates = _router({"router": {"w": wr}}, ctx.cfg, x_loc)
+    with scope("moe/pack"):
+        order, sizes = _geom.sort_by_group(idx.reshape(-1), m.n_experts)
+        xs = x_loc[order // m.top_k]  # [t*k, d], grouped by expert
+    with scope("moe/expert_ffn"):
+        ys = _expert_ffn_sorted(xs, sizes.astype(jnp.int32), wg, wu, wd, layer)
+    with scope("moe/combine"):
+        # back to (token, choice) order, then each token's k rows summed
+        unsort = jnp.zeros((tk,), jnp.int32).at[order].set(
+            jnp.arange(tk, dtype=jnp.int32)
+        )
+        y = ys[unsort].astype(jnp.float32).reshape(t, m.top_k, d)
+        y_loc = (y * gates[..., None]).sum(axis=1)  # [t, d] f32
+    if not return_stats:
+        return y_loc
+    counts = _routing_counts(idx, m.n_experts, weight=token_weight)
+    return y_loc, _stats(counts[None, :], tk, tk)  # every choice computed
+
+
 def _pipeline_body(
     fabric, ctx: FabricContext, x_loc, wr, wg, wu, wd, *, return_stats, ep,
     token_weight=None,
@@ -194,23 +382,37 @@ def _pipeline_body(
 
 def _moe_virtual(
     params, cfg: ModelConfig, x, fabric, schedule, return_stats,
-    token_weight=None,
+    token_weight=None, experts: StackExperts | None = None,
 ):
-    """Run the pipeline without a mesh (the dense/virtual fabric)."""
+    """Run the pipeline without a mesh (the dense/virtual fabric), or
+    the sorted pipeline where ``_sorted_path`` allows it.  ``experts``:
+    the expert weights as a stack and a layer index, in place of
+    ``params``' own (``hold_stack_experts``)."""
     b, s, d = x.shape
     t = b * s
     ctx = FabricContext(
         cfg=cfg, n=1, e_local=cfg.moe.n_experts, axis=None, me=None,
         schedule=schedule, two_d=False, t_local=t,
     )
-    res = _pipeline_body(
-        fabric, ctx, x.reshape(t, d),
-        params["router"]["w"], params["w_gate"], params["w_up"],
-        params["w_down"], return_stats=return_stats, ep=False,
-        token_weight=(
-            None if token_weight is None else token_weight.reshape(t)
-        ),
-    )
+    tw = None if token_weight is None else token_weight.reshape(t)
+    wr = params["router"]["w"]
+    if _sorted_path(cfg, t, schedule):
+        if experts is None:
+            ws, layer = tuple(params[k] for k in _EXPERT_WEIGHTS), None
+        else:
+            ws, layer = experts[:3], experts.layer
+        res = _sorted_body(
+            ctx, x.reshape(t, d), wr, *ws, return_stats=return_stats,
+            token_weight=tw, layer=layer,
+        )
+    else:
+        if experts is not None:
+            params = {**params, **experts.sliced()}
+        res = _pipeline_body(
+            fabric, ctx, x.reshape(t, d), wr,
+            *(params[k] for k in _EXPERT_WEIGHTS),
+            return_stats=return_stats, ep=False, token_weight=tw,
+        )
     if not return_stats:
         return res.astype(x.dtype).reshape(b, s, d)
     y, stats = res
@@ -377,6 +579,7 @@ def moe_apply(
     schedule=None,
     return_stats: bool = False,
     token_weight: jax.Array | None = None,
+    experts: StackExperts | None = None,
 ):
     """Apply the MoE FFN through the fabric named by ``cfg.moe.dispatch``.
 
@@ -400,6 +603,9 @@ def moe_apply(
     token's contribution to ``routing`` — the serving engine passes its
     decode-slot liveness mask so vacated slots in a static-shape batch
     never register as expert demand.  The forward values are untouched.
+
+    ``experts`` (``hold_stack_experts``) carries the expert weights as a
+    whole stack's and the layer to run, in place of ``params``' own.
     """
     m = cfg.moe
     mode = m.dispatch
@@ -418,8 +624,10 @@ def moe_apply(
         fabric = get_fabric("dense")
         return _moe_virtual(
             params, cfg, x, fabric, fabric.validate_schedule(schedule, n=1),
-            return_stats, token_weight=token_weight,
+            return_stats, token_weight=token_weight, experts=experts,
         )
+    if experts is not None:
+        params = {**params, **experts.sliced()}
     fabric = resolve_fabric(mode, schedule)
     sched = fabric.validate_schedule(schedule, n=n)
     if not fabric.uses_mesh:

@@ -33,7 +33,7 @@ from repro.models.layers import (
     swiglu_apply,
     swiglu_init,
 )
-from repro.models.moe import moe_apply, moe_init
+from repro.models.moe import hold_stack_experts, moe_apply, moe_init
 from repro.scopes import scope
 
 # each block's mixer sublayer (its norm and residual too) runs under its
@@ -77,13 +77,16 @@ def moe_positions(cfg: ModelConfig) -> list[int]:
     return [j for j in range(cfg.period) if cfg.ffn_kind(j) == "moe"]
 
 
-def _ffn_apply(p, cfg, j, x, schedule, collect_stats=False, token_weight=None):
+def _ffn_apply(
+    p, cfg, j, x, schedule, collect_stats=False, token_weight=None, experts=None,
+):
     """Returns (y, routing-stats-or-None).  ``token_weight`` ([B, S] f32)
-    is the stats-only liveness weight forwarded to ``moe_apply``."""
+    is the stats-only liveness weight, and ``experts`` the expert weights
+    held out of a layer scan, both forwarded to ``moe_apply``."""
     if cfg.ffn_kind(j) == "moe":
         out = moe_apply(
             p["ffn"], cfg, x, schedule=schedule, return_stats=collect_stats,
-            token_weight=token_weight,
+            token_weight=token_weight, experts=experts,
         )
         return out if collect_stats else (out, None)
     if cfg.ffn_gelu:
@@ -137,7 +140,7 @@ def block_cache(cfg: ModelConfig, j: int, batch: int, max_len: int, dtype=jnp.bf
     return rk.rwkv_init_state(cfg, batch, dtype)
 
 
-def block_prefill(p, cfg, j, x, cache, schedule):
+def block_prefill(p, cfg, j, x, cache, schedule, experts=None):
     kind = cfg.layer_kind(j)
     with scope(MIXER_SCOPES[kind]):
         h = rmsnorm_apply(p["ln1"], x, eps=cfg.norm_eps)
@@ -157,7 +160,7 @@ def block_prefill(p, cfg, j, x, cache, schedule):
             return x, (x_tm.astype(cache[0].dtype), s, x_cm.astype(cache[2].dtype))
     with scope(_ffn_scope(cfg, j)):
         h = rmsnorm_apply(p["ln2"], x, eps=cfg.norm_eps)
-        x = x + _ffn_apply(p, cfg, j, h, schedule)[0]
+        x = x + _ffn_apply(p, cfg, j, h, schedule, experts=experts)[0]
         return x, cache
 
 
@@ -346,20 +349,32 @@ def stack_train(
 def stack_prefill(params, cfg: ModelConfig, x, caches, schedule):
     shared, rows = _schedule_rows(schedule, cfg)
     positions = moe_positions(cfg)
+    # MoE positions whose layers run the sorted pipeline read their expert
+    # weights whole, by layer index, where the scan's slice would copy them
+    held = {}
+    for j in positions:
+        ffn, experts = hold_stack_experts(
+            params[f"pos{j}"]["ffn"], cfg, x.shape[0] * x.shape[1], schedule
+        )
+        if experts is not None:
+            params = {**params, f"pos{j}": {**params[f"pos{j}"], "ffn": ffn}}
+            held[j] = experts
+    layers = (jnp.arange(cfg.n_periods),) if held else ()
 
     def scan_fn(carry, inp):
-        pparams, pcache, prow = inp
+        pparams, pcache, prow, *layer = inp
         new = {}
         for j in range(cfg.period):
             carry, c = block_prefill(
                 pparams[f"pos{j}"], cfg, j, carry, pcache[f"pos{j}"],
                 _position_schedule(prow, shared, positions, j),
+                experts=held[j]._replace(layer=layer[0]) if j in held else None,
             )
             new[f"pos{j}"] = c
         return carry, new
 
     with scope("stack"):
-        x, caches = jax.lax.scan(scan_fn, x, (params, caches, rows))
+        x, caches = jax.lax.scan(scan_fn, x, (params, caches, rows, *layers))
     return x, caches
 
 

@@ -21,8 +21,6 @@ Doubles as two fallbacks the resolver relies on:
 
 from __future__ import annotations
 
-import math
-
 import jax.numpy as jnp
 
 from repro.core.hierarchical import HierarchicalTable, check_pod_size
@@ -73,9 +71,7 @@ class DenseFabric(Fabric):
             gates, admitted = g.admission_mask(
                 idx, gates, row, m.n_experts, src=src
             )
-        cap = g.round8(
-            math.ceil(t * m.top_k / m.n_experts * m.capacity_factor)
-        )
+        cap = g.bucket_capacity(t, m)
         buf, pos, gate, live = g.group_tokens(
             x_loc, idx.reshape(-1), gates.reshape(-1), m.n_experts, cap,
             admitted=admitted,

@@ -16,6 +16,8 @@ re-exports the old underscore names for its tests.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,6 +26,8 @@ from repro.core.schedule import ScheduleTable
 
 __all__ = [
     "round8",
+    "bucket_capacity",
+    "sort_by_group",
     "group_tokens",
     "pack_slots",
     "ungroup",
@@ -62,6 +66,24 @@ def round8(x):
     return int(r) if r.ndim == 0 else r
 
 
+def bucket_capacity(t: int, moe) -> int:
+    """Slots per expert bucket of a ``t``-token layer: the choices an
+    expert would get under even routing, times ``moe.capacity_factor``,
+    rounded up to a multiple of 8."""
+    return round8(math.ceil(t * moe.top_k / moe.n_experts * moe.capacity_factor))
+
+
+def sort_by_group(key, n_groups: int):
+    """Stable sort of elements by group id.
+
+    ``key``: [N] int group ids.  Returns (order [N] — the element indices
+    grouped by id, ascending, arrival order kept within a group — and
+    counts [n_groups] int32, the size of each group).  The one sort both
+    expert pipelines share: the padded path ranks choices into bucket
+    slots from it, the sorted path runs its grouped GEMM over it."""
+    return jnp.argsort(key, stable=True), jnp.bincount(key, length=n_groups)
+
+
 def group_tokens(x, key, gates, n_buckets: int, cap: int, admitted=None):
     """Pack tokens into per-bucket slots.
 
@@ -80,9 +102,8 @@ def group_tokens(x, key, gates, n_buckets: int, cap: int, admitted=None):
     tk = key.shape[0]
     t = x.shape[0]
     token_of = jnp.arange(tk, dtype=jnp.int32) // (tk // t)
-    order = jnp.argsort(key)
+    order, counts = sort_by_group(key, n_buckets)
     skey = key[order]
-    counts = jnp.bincount(key, length=n_buckets)
     starts = jnp.concatenate(
         [jnp.zeros(1, counts.dtype), jnp.cumsum(counts)[:-1]]
     )

@@ -61,6 +61,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.launch.rules import dtype_policy
 from repro.models import Model
+from repro.models.moe import expert_path, expert_rows
 from repro.scopes import scope
 from repro.serve.batcher import ContinuousBatcher
 from repro.serve.metrics import ServeMetrics, register_program
@@ -206,6 +207,9 @@ class ServeEngine:
 
         self._admit_jit = jax.jit(_admit)
         self._row_template = model.init_cache(1, max_len, cache_dtype)
+        self._n_moe_layers = sum(
+            cfg.ffn_kind(i) == "moe" for i in range(cfg.n_layers)
+        )
         self._caches = model.init_cache(decode_slots, max_len, cache_dtype)
 
     # ----------------------------------------------------------- controller
@@ -352,6 +356,11 @@ class ServeEngine:
                 self._prefill, *args, variant=bucket, schedule=self._prefill_table
             )
             _, row = self._prefill(*args, schedule=self._prefill_table)
+            if self._n_moe_layers:
+                computed, routed = expert_rows(self.cfg, bucket, self._prefill_table)
+                self._metrics.record_expert_rows(
+                    computed * self._n_moe_layers, routed * self._n_moe_layers
+                )
         return row, plen
 
     def _admit_ready(self, step_no: int, wall: float) -> None:
@@ -363,7 +372,12 @@ class ServeEngine:
                 return
             with TraceAnnotation("serve.engine.admit") as span:
                 req, bucket = self.queue.pop()
-                span.set_metadata(rid=req.rid, bucket=bucket, slot=slot)
+                meta = {"rid": req.rid, "bucket": bucket, "slot": slot}
+                if self._n_moe_layers:
+                    meta["expert_path"] = expert_path(
+                        self.cfg, bucket, self._prefill_table
+                    )
+                span.set_metadata(**meta)
                 row, plen = self._prefill_row(req, bucket)
                 args = (self._caches, row, jnp.int32(slot), jnp.int32(plen))
                 register_program(self._admit_jit, *args)

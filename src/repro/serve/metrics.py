@@ -53,6 +53,9 @@ class ServeMetrics:
         self.live_slot_steps = 0  # sum of live counts over decode steps
         self.n_slots = 0
         self.wall_s = 0.0
+        # prefill expert GEMM rows, over the MoE layers of each admission
+        self.expert_rows_computed = 0
+        self.expert_rows_routed = 0
 
     # ------------------------------------------------------------- events
     def record_offered(self, n: int = 1) -> None:
@@ -65,6 +68,10 @@ class ServeMetrics:
     def record_admitted(self, req: Request, step_no: int) -> None:
         self.admitted += 1
         self.queue_wait_steps.append(float(step_no - req.arrival))
+
+    def record_expert_rows(self, computed: int, routed: int) -> None:
+        self.expert_rows_computed += int(computed)
+        self.expert_rows_routed += int(routed)
 
     def record_decode_step(self, n_live: int) -> None:
         self.decode_steps += 1
@@ -99,6 +106,11 @@ class ServeMetrics:
             "idle_steps": self.idle_steps,
             "occupancy": self.live_slot_steps
             / max(self.decode_steps * max(self.n_slots, 1), 1),
+            "expert_rows_computed": self.expert_rows_computed,
+            "expert_rows_routed": self.expert_rows_routed,
+            # routed rows over rows the prefills' expert GEMMs computed
+            "expert_row_fill": self.expert_rows_routed
+            / max(self.expert_rows_computed, 1),
         }
 
 
