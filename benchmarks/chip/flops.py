@@ -35,3 +35,13 @@ def attention_flops(dm: dict, context: int) -> int:
 def decode_flops(dm: dict, contexts) -> int:
     """One decode step: each live slot's token at its context length."""
     return sum(2 * active_params(dm) + attention_flops(dm, c) for c in contexts)
+
+
+def train_flops(dm: dict, batch: int, seq: int) -> int:
+    """Model FLOPs of one training step over ``batch`` causal sequences
+    of ``seq`` tokens: forward and backward, 3 times the forward's 2 per
+    active parameter and its attention at each position's context
+    (``p + 1`` positions at position ``p``).  Recomputation is not
+    counted."""
+    contexts = seq * (seq + 1) // 2
+    return batch * 3 * (2 * active_params(dm) * seq + attention_flops(dm, contexts))

@@ -87,10 +87,14 @@ def make(key, names: dict, layer: int, served_dtype) -> dict:
 
 # ------------------------------------------------------------------- maths
 def _q8(x, axis):
-    """Round to float8 with one scale per slice along ``axis``."""
+    """Round to float8 with one scale per slice along ``axis``.  The
+    gradient passes straight through the rounding: differentiating the
+    cast itself would round the unscaled cotangent to float8 and flush
+    it to zero.  The value is the rounded one, to float32 round-off."""
     s = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / F8_MAX
     s = jnp.where(s > 0, s, 1.0)
-    return (x / s).astype(F8).astype(jnp.float32) * s
+    q = (x / s).astype(F8).astype(jnp.float32) * s
+    return x + jax.lax.stop_gradient(q - x)
 
 
 def mm(x, w, low: bool):

@@ -6,15 +6,18 @@ import shutil
 import subprocess
 import sys
 
+import pytest
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(HERE)))
-ARGS = ["--workload", "mixtral.serve.steady", "--seed", "1", "--seconds", "1", "--trace", "0"]
+ARGS = ["--seed", "1", "--seconds", "1", "--trace", "0"]
 
 
-def _run(root):
+def _run(root, workload="mixtral.serve.steady"):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     return subprocess.run(
-        [sys.executable, os.path.join(root, "benchmarks", "chip", "run.py"), *ARGS],
+        [sys.executable, os.path.join(root, "benchmarks", "chip", "run.py"), "--workload",
+         workload, *ARGS],
         cwd=root, env=env, capture_output=True, text=True, timeout=300,
     )
 
@@ -24,8 +27,9 @@ def _no_result(proc):
     return not any(line.startswith("{") for line in last)
 
 
-def test_refuses_without_a_tpu():
-    proc = _run(ROOT)
+@pytest.mark.parametrize("workload", ["mixtral.serve.steady", "mixtral.train.ep4.skewed"])
+def test_refuses_without_a_tpu(workload):
+    proc = _run(ROOT, workload)
     assert proc.returncode != 0
     assert _no_result(proc)
     assert "no TPU" in proc.stderr
