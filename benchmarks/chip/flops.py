@@ -1,9 +1,12 @@
-"""Operations of the model step, counted from shapes (``dims`` as
-``reference.mixtral.dims`` gives them), and the chip's peaks.
+"""Operations of the model step, counted from shapes (``dims`` as the
+configuration's reference module gives them), and the chip's peaks.
 
 Model FLOPs per token are 2 times the active non-embedding parameters
 (forward), the LM head included, plus attention's 4 * d_attn * context
-per layer.  Capacity padding is not counted.
+per layer.  Capacity padding is not counted.  Where the chip holds
+``e_held`` of a layer's ``e`` experts, a token's ``k`` choices land on
+it ``k * e_held / e`` times in expectation, and that is the number of
+experts counted for each token (rounded down to a whole parameter).
 """
 
 from __future__ import annotations
@@ -20,9 +23,11 @@ def peaks(device_kind: str) -> dict:
 
 
 def active_params(dm: dict) -> int:
-    """Active non-embedding parameters per token, LM head included."""
+    """Active non-embedding parameters per token, LM head included; the
+    experts are this chip's expected share of the token's choices."""
     d, f, q, kv = dm["d"], dm["f"], dm["h"] * dm["hd"], dm["kv"] * dm["hd"]
-    per_layer = d * q + 2 * d * kv + q * d + d * dm["e"] + dm["k"] * 3 * d * f + 2 * d
+    experts = dm["k"] * dm["e_held"] * 3 * d * f // dm["e"]
+    per_layer = d * q + 2 * d * kv + q * d + d * dm["e"] + experts + 2 * d
     return dm["layers"] * per_layer + d * dm["v"] + d
 
 

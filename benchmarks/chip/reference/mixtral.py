@@ -13,6 +13,10 @@ by ``weights.leaf`` under the names the benchmark gave them.
 product taken in float8 (e4m3, one scale per row of the activations and
 per output column of the weights), the precision below the bfloat16
 that the configurations state.
+
+For the harness (``runners/common.py``) it is the reference a serving
+configuration names: ``KEYS``, ``published``, ``implements``, ``dims``
+and ``serve_logits``.
 """
 
 from __future__ import annotations
@@ -28,8 +32,57 @@ import weights as W
 F8 = jnp.float8_e4m3fn
 F8_MAX = 448.0
 
+# keys of the published config.json that a configuration file matches
+KEYS = (
+    "hidden_size", "intermediate_size", "num_attention_heads", "num_key_value_heads",
+    "head_dim", "num_hidden_layers", "num_local_experts", "num_experts_per_tok",
+    "vocab_size", "rope_theta", "rms_norm_eps", "sliding_window", "tie_word_embeddings",
+)
+
+
+def published(cfg) -> dict:
+    """The program's ``ModelConfig`` read back under ``KEYS``."""
+    return {
+        "hidden_size": cfg.d_model,
+        "intermediate_size": cfg.moe.d_ff_expert,
+        "num_attention_heads": cfg.n_heads,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "head_dim": cfg.resolved_head_dim,
+        "num_hidden_layers": cfg.n_layers,
+        "num_local_experts": cfg.moe.n_experts,
+        "num_experts_per_tok": cfg.moe.top_k,
+        "vocab_size": cfg.vocab_size,
+        "rope_theta": cfg.rope_theta,
+        "rms_norm_eps": cfg.norm_eps,
+        "sliding_window": cfg.sliding_window,
+        "tie_word_embeddings": cfg.tie_embeddings,
+    }
+
+
+def implements(cfg) -> str | None:
+    """Why this reference does not compute the program's ``ModelConfig``
+    (the block it departs in), or None where it does."""
+    m = cfg.moe
+    needs = {
+        "attention blocks": cfg.block == "attn" and cfg.hybrid is None,
+        "rotary positions": cfg.pos_embedding == "rope",
+        "no q/k/v bias": not cfg.qkv_bias,
+        "SwiGLU experts": not cfg.ffn_gelu,
+        "token inputs": cfg.frontend == "none",
+        "an expert layer in every block": m.every == 1,
+        "top-k gates renormalized": m.router_norm_topk,
+        "whole expert widths (no 2D sharding)": not m.expert_2d,
+        "bfloat16 on the wire": m.wire_dtype == "bf16",
+    }
+    wrong = [k for k, ok in needs.items() if not ok]
+    if wrong:
+        return "not the decoder block the reference implements: it needs " + ", ".join(wrong)
+    return None
+
 
 def dims(cfg: dict) -> dict:
+    """Shapes under short names, from the published keys; every expert
+    is held on the chip (``e_held``)."""
     return {
         "d": cfg["hidden_size"],
         "f": cfg["intermediate_size"],
@@ -37,6 +90,7 @@ def dims(cfg: dict) -> dict:
         "kv": cfg["num_key_value_heads"],
         "hd": cfg["head_dim"],
         "e": cfg["num_local_experts"],
+        "e_held": cfg["num_local_experts"],
         "k": cfg["num_experts_per_tok"],
         "v": cfg["vocab_size"],
         "layers": cfg["num_hidden_layers"],

@@ -31,6 +31,10 @@ reference put in its place: ``no_exchange`` (each token reaches only the
 experts of the rank that holds it: a rank holds an equal slice of every
 sequence's positions and an equal share of the experts), ``half_batch``
 (the loss over the first half of the sequences only).
+
+For the harness (``runners/common.py``) it is the reference a training
+configuration names: ``reference.mixtral``'s ``KEYS``, ``published``,
+``implements`` and ``dims``, and ``train_readings``.
 """
 
 from __future__ import annotations
@@ -44,6 +48,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import weights as W
 from reference import mixtral as R
+from reference.mixtral import KEYS, dims, implements, published  # noqa: F401  (the contract)
 
 EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 TOKEN_BLOCK = 1024
@@ -250,7 +255,7 @@ def train_readings(seed: int, cfg: dict, opt: dict, batches: list[dict], *, devi
     Returns each step's loss, the norm of each leaf of the first step's
     gradient as the optimizer takes it (after clipping), and the norm of
     each leaf's change over the steps."""
-    dm = R.dims(cfg)
+    dm = dims(cfg)
     mesh = Mesh(np.asarray(devices), ("x",))
     train_step = step_fn(dm, opt, low=low, fault=fault, n_ranks=n_ranks)
 

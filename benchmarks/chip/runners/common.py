@@ -1,57 +1,56 @@
-"""Model configuration from a configuration file, shared by the runners."""
+"""Model configuration from a configuration file, shared by the runners.
+
+A configuration file names its plain reference, ``"reference":
+"<module>"``, a module of ``reference/``, and the harness reads all that
+is particular to the model from that module.  Every reference module
+provides:
+
+- ``KEYS``: the published ``config.json`` keys the file must match, under
+  the model's own names;
+- ``published(cfg) -> dict``: the program's ``ModelConfig`` read back
+  under those keys;
+- ``implements(cfg) -> str | None``: why the reference does not compute
+  ``cfg``, or None where it does;
+- ``dims(hf) -> dict``: the shapes ``flops`` counts from, ``e_held`` (the
+  experts held on this chip) among them;
+- for the serving runner ``serve_logits(seed, hf, seqs, rows, low=False,
+  lengths=None)``, for the training runner ``train_readings(seed, hf,
+  opt, batches, *, devices, n_ranks, low=False, fault=None)``.
+"""
 
 from __future__ import annotations
 
 import dataclasses
-
-# keys of the published configuration, against the program's config
-HF_KEYS = (
-    "hidden_size", "intermediate_size", "num_attention_heads", "num_key_value_heads",
-    "head_dim", "num_hidden_layers", "num_local_experts", "num_experts_per_tok",
-    "vocab_size", "rope_theta", "rms_norm_eps", "sliding_window", "tie_word_embeddings",
-)
+import importlib
 
 
 def model_config(conf: dict, tiny: bool):
     """The program's ``ModelConfig`` for this file (smoke widths with
-    ``tiny``), and the published-style dict the reference reads."""
+    ``tiny``), the published-style dict the reference reads, and the
+    reference module the file names."""
     from repro.configs import get_config, smoke_config
 
+    where = f"configs/{conf['name']}.json"
+    if "reference" not in conf:
+        raise SystemExit(f"{where}: names no reference module (key 'reference')")
+    try:
+        ref = importlib.import_module("reference." + conf["reference"])
+    except ModuleNotFoundError as e:
+        if e.name != "reference." + conf["reference"]:
+            raise
+        raise SystemExit(f"{where}: no reference module {conf['reference']!r}") from None
     base = smoke_config(conf["registry"]) if tiny else get_config(conf["registry"])
     cfg = dataclasses.replace(
         base,
         **conf["overrides"],
         moe=dataclasses.replace(base.moe, **conf["moe_overrides"]),
     )
-    hf = published(cfg)
+    hf = ref.published(cfg)
     if not tiny:
-        wrong = {k: (hf[k], conf[k]) for k in HF_KEYS if hf[k] != conf[k]}
+        wrong = {k: (hf[k], conf[k]) for k in ref.KEYS if hf[k] != conf[k]}
         if wrong:
-            raise SystemExit(f"{conf['name']}: the program's config differs from the file: {wrong}")
-    # the reference implements exactly this block
-    m = cfg.moe
-    if not (
-        cfg.block == "attn" and cfg.hybrid is None and cfg.pos_embedding == "rope"
-        and not cfg.qkv_bias and not cfg.ffn_gelu and cfg.frontend == "none"
-        and m.every == 1 and m.router_norm_topk and not m.expert_2d and m.wire_dtype == "bf16"
-    ):
-        raise SystemExit(f"{conf['name']}: not the decoder block the reference implements")
-    return cfg, hf
-
-
-def published(cfg) -> dict:
-    return {
-        "hidden_size": cfg.d_model,
-        "intermediate_size": cfg.moe.d_ff_expert,
-        "num_attention_heads": cfg.n_heads,
-        "num_key_value_heads": cfg.n_kv_heads,
-        "head_dim": cfg.resolved_head_dim,
-        "num_hidden_layers": cfg.n_layers,
-        "num_local_experts": cfg.moe.n_experts,
-        "num_experts_per_tok": cfg.moe.top_k,
-        "vocab_size": cfg.vocab_size,
-        "rope_theta": cfg.rope_theta,
-        "rms_norm_eps": cfg.norm_eps,
-        "sliding_window": cfg.sliding_window,
-        "tie_word_embeddings": cfg.tie_embeddings,
-    }
+            raise SystemExit(f"{where}: the program's config differs from the file: {wrong}")
+    why = ref.implements(cfg)
+    if why is not None:
+        raise SystemExit(f"{where}: reference.{conf['reference']}: {why}")
+    return cfg, hf, ref

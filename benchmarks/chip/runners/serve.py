@@ -30,7 +30,6 @@ from collections import deque
 import numpy as np
 
 import harness as H
-from reference import mixtral as R
 from runners.common import model_config
 
 # CPU rehearsal: smoke widths, short prompts and outputs
@@ -160,6 +159,7 @@ class Setup:
     traffic: dict
     cfg: object
     hf: dict
+    ref: object  # the reference module the configuration names
     dm: dict
     info: dict
     peak: dict | None
@@ -186,7 +186,7 @@ def setup(args, cell) -> Setup:
         traffic["topics"] = dict(traffic["topics"], ids_per_topic=TINY_TRAFFIC["ids_per_topic"])
     if getattr(args, "rate", None) is not None:
         traffic["rate_rps"] = args.rate
-    cfg, hf = model_config(conf, args.tiny)
+    cfg, hf, ref = model_config(conf, args.tiny)
     info = H.device_info()
     peak = F.peaks(info["kind"]) if info["platform"] == "tpu" else None
     shapes = jax.eval_shape(Model(cfg).init, jax.random.PRNGKey(0))
@@ -213,8 +213,8 @@ def setup(args, cell) -> Setup:
     if compiled != want:
         raise SystemExit(f"warm-up compiled {compiled}, expected {want}")
     loop.tokens.clear(), loop.admitted.clear(), loop.steps.clear()
-    return Setup(engine, params, loop, gen, eng_conf, traffic, cfg, hf, R.dims(hf), info,
-                 peak, compiled)
+    return Setup(engine, params, loop, gen, eng_conf, traffic, cfg, hf, ref, ref.dims(hf),
+                 info, peak, compiled)
 
 
 def run(args, cell, t_process: float) -> tuple[dict, list]:
@@ -225,7 +225,7 @@ def run(args, cell, t_process: float) -> tuple[dict, list]:
     su = setup(args, cell)
     engine, params, loop, gen = su.engine, su.params, su.loop, su.gen
     eng_conf, traffic, hf, dm, info, peak = su.eng_conf, su.traffic, su.hf, su.dm, su.info, su.peak
-    compiled, spans = su.compiled, su.loop.spans
+    compiled, spans, reference = su.compiled, su.loop.spans, su.ref
     del su
     replans0 = _replans(engine)
 
@@ -298,14 +298,14 @@ def run(args, cell, t_process: float) -> tuple[dict, list]:
     seqs, rows, served, lengths = _check_rows(sample, eng_conf["max_len"])
     del engine, params, loop, reqs, finished
     gc.collect()
-    ref, load = R.serve_logits(args.seed, hf, seqs, rows, lengths=lengths)
+    ref, load = reference.serve_logits(args.seed, hf, seqs, rows, lengths=lengths)
     _print_skew(rec, load)
     best = ref.max(axis=-1)
     gaps = best - ref[np.arange(len(served)), served]
     if args.control:
         print(f"program gap_mean: {float(gaps.mean())!r}, gap_max: {float(gaps.max())!r}",
               file=sys.stderr)
-        low = R.serve_logits(args.seed, hf, seqs, rows, low=True).argmax(axis=-1)
+        low = reference.serve_logits(args.seed, hf, seqs, rows, low=True).argmax(axis=-1)
         gaps = best - ref[np.arange(len(served)), low]
         print("control: the float8 reference's first choices stand in for the served tokens",
               file=sys.stderr)

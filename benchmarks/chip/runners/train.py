@@ -45,8 +45,6 @@ import types
 import numpy as np
 
 import harness as H
-from reference import mixtral as R
-from reference import mixtral_train as RT
 from runners.common import model_config
 
 # CPU rehearsal: smoke widths, short sequences and documents, a small
@@ -221,6 +219,7 @@ def _print_demand(counts, scheds, table, e_local, t_local, k, c_local):
 @dataclasses.dataclass
 class Setup:
     hf: dict
+    ref: object  # the reference module the configuration names
     opt_conf: dict
     mesh: object
     step: object  # the compiled step
@@ -285,7 +284,7 @@ def program(cell, tiny: bool) -> types.SimpleNamespace:
     if tiny:
         traffic.update(TINY_TRAFFIC)
         traffic["topics"] = dict(traffic["topics"], ids_per_topic=TINY_IDS_PER_TOPIC)
-    cfg, hf = model_config(conf, tiny)
+    cfg, hf, ref = model_config(conf, tiny)
     dtypes = dtype_policy(cfg)
     if dtypes["param_dtype"] != jnp.float32 or dtypes["moment_dtype"] != jnp.float32:
         raise SystemExit(f"the program keeps {dtypes}, the configuration states float32")
@@ -317,7 +316,7 @@ def program(cell, tiny: bool) -> types.SimpleNamespace:
     shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
                              is_leaf=lambda x: isinstance(x, P))
     return types.SimpleNamespace(
-        conf=conf, cfg=cfg, hf=hf, mesh=mesh, rules=rules, n=n, e_local=e_local,
+        conf=conf, cfg=cfg, hf=hf, ref=ref, mesh=mesh, rules=rules, n=n, e_local=e_local,
         t_local=t_local, top_k=m.top_k,
         # the local bucket: every choice of the rank's own experts, at the
         # configuration's capacity factor
@@ -382,10 +381,10 @@ def setup(args, cell) -> Setup:
     cut = sum(cut_choices(s["routing"], slots, e_local) for s in stats)
     dropped = int(sum(s["dropped"].sum() for s in stats))
     scopes = S.table_of(step.as_text()) if args.trace else {}
-    dm = R.dims(pr.hf)
+    dm = pr.ref.dims(pr.hf)
     return Setup(
-        hf=pr.hf, opt_conf=conf["optimizer"], mesh=mesh, step=step, state=state, pool=pool,
-        pool_dev=pool_dev, table=table, phases=int(np.max(np.asarray(table.n_phases))),
+        hf=pr.hf, ref=pr.ref, opt_conf=conf["optimizer"], mesh=mesh, step=step, state=state,
+        pool=pool, pool_dev=pool_dev, table=table, phases=int(np.max(np.asarray(table.n_phases))),
         slots=slots, e_local=e_local, info=info, peak=peak, scopes=scopes,
         readings=readings, cut=cut, dropped=dropped,
         tokens_per_step=pr.gen.tokens_per_batch,
@@ -411,13 +410,14 @@ def _print_memory(step, devices) -> None:
 
 
 # ------------------------------------------------------------------- run
-def reference_readings(seed, hf, opt_conf, pool, devices, n_ranks, low=False, fault=None):
-    """The reference's readings of the first steps (``low``, ``fault``:
-    see ``reference.mixtral_train``)."""
+def reference_readings(reference, seed, hf, opt_conf, pool, devices, n_ranks, low=False,
+                       fault=None):
+    """The readings of the first steps by the ``reference`` module
+    (``low``, ``fault``: see its ``train_readings``)."""
     import jax
 
     with jax.default_matmul_precision("highest"):
-        return RT.train_readings(
+        return reference.train_readings(
             seed, hf, opt_conf, pool[:CHECK_STEPS], devices=devices,
             n_ranks=n_ranks, low=low, fault=fault,
         )
@@ -501,17 +501,18 @@ def run(args, cell, t_process: float) -> tuple[dict, list]:
           f"last quarter {gaps[-q:].mean():.2f}", file=sys.stderr)
 
     # ------------------------------------------------------- correctness
-    prog, pool, hf, o, info = su.readings, su.pool, su.hf, su.opt_conf, su.info
+    prog, pool, hf, o, info, reference = (su.readings, su.pool, su.hf, su.opt_conf, su.info,
+                                          su.ref)
     n_ranks, cut, dropped = su.mesh.shape["model"], su.cut, su.dropped
     del su, step, state, pool_dev, table, params, opt_state, ef, out, rec
     gc.collect()
     t0 = time.perf_counter()
-    ref = reference_readings(args.seed, hf, o, pool, devs, n_ranks)
+    ref = reference_readings(reference, args.seed, hf, o, pool, devs, n_ranks)
     print(f"reference: {time.perf_counter() - t0:.1f} s", file=sys.stderr)
     if args.control:
         print("control: the float8 reference stands in for the program", file=sys.stderr)
         print(f"program losses {prog['loss']}", file=sys.stderr)
-        prog = reference_readings(args.seed, hf, o, pool, devs, n_ranks, low=True)
+        prog = reference_readings(reference, args.seed, hf, o, pool, devs, n_ranks, low=True)
     numbers, left_out = compare(prog, ref)
     print(f"losses: program {prog['loss']}, reference {ref['loss']}; leaves left out "
           f"(first gradient under {NEGLIGIBLE_GRAD} of the median leaf's): {left_out}",

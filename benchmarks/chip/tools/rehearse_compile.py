@@ -42,7 +42,7 @@ def serve(topo) -> None:
     from runners.common import model_config
 
     conf = H.load_json(H.HERE, "configs", "mixtral-8x7b.serve.json")
-    cfg, _ = model_config(conf, tiny=False)
+    cfg, _, _ = model_config(conf, tiny=False)
     e = conf["engine"]
     one = SingleDeviceSharding(topo.devices[0])
     from repro.models import Model

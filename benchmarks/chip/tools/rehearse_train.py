@@ -5,8 +5,9 @@ without the chip.
 
 Compiles the cell's train step (its mesh, rules, shardings and the phase
 table's envelope from the configuration), the forward that routes the pool, and with
-``--reference`` the plain reference's step in float32 and in float8 on
-the same four chips, and prints ``memory_analysis()`` of each and the
+``--reference`` the step of the reference the configuration names
+(its ``leaves``, ``per_leaf``, ``sharding_of`` and ``step_fn``) in float32
+and in float8 on the same four chips, and prints ``memory_analysis()`` of each and the
 collectives in the train step.  Nothing runs: no time is measured.
 """
 
@@ -51,8 +52,6 @@ def main() -> int:
     from jax.experimental import topologies
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from reference import mixtral as R
-    from reference import mixtral_train as RT
     from repro.core.schedule import A2ASchedule, ScheduleTable
     from repro.launch.rules import train_rules
     from repro.models import Model
@@ -64,7 +63,7 @@ def main() -> int:
 
     cell = H.cell(WORKLOAD)
     conf = cell["config"]
-    cfg, hf = model_config(conf, tiny=False)
+    cfg, hf, ref = model_config(conf, tiny=False)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     mesh = auto_mesh((conf["mesh"]["data"], conf["mesh"]["model"]), ("data", "model"),
                      devices=topo.devices)
@@ -115,18 +114,18 @@ def main() -> int:
             state["params"], batch).compile()
         report("routing forward", route, t0)
     if args.reference:
-        dm = R.dims(hf)
+        dm = ref.dims(hf)
         rmesh = Mesh(np.asarray(topo.devices), ("x",))
-        spec = RT.leaves(dm)
-        params = RT.per_leaf(dm, lambda k: jax.ShapeDtypeStruct(
-            spec[k][1], jnp.float32, sharding=RT.sharding_of(k, rmesh)))
+        spec = ref.leaves(dm)
+        params = ref.per_leaf(dm, lambda k: jax.ShapeDtypeStruct(
+            spec[k][1], jnp.float32, sharding=ref.sharding_of(k, rmesh)))
         tok = jax.ShapeDtypeStruct((gen.batch, gen.sequence), jnp.int32,
                                    sharding=NamedSharding(rmesh, P()))
         num = jax.ShapeDtypeStruct((), jnp.float32, sharding=NamedSharding(rmesh, P()))
         for name, kw in (("reference", {}), ("reference float8", {"low": True}),
                          ("reference no exchange", {"fault": "no_exchange"})):
             t0 = time.perf_counter()
-            fn = RT.step_fn(dm, o, n_ranks=n, **kw)
+            fn = ref.step_fn(dm, o, n_ranks=n, **kw)
             report(name, fn.lower(params, params, params, tok, tok, num).compile(), t0)
     return 0
 

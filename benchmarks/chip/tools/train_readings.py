@@ -63,15 +63,15 @@ def main() -> int:
         t0 = time.perf_counter()
         run_args = argparse.Namespace(seed=seed, tiny=args.tiny, trace=0)
         su = TR.setup(run_args, cell)
-        prog, pool, hf, o = su.readings, su.pool, su.hf, su.opt_conf
+        prog, pool, hf, o, reference = su.readings, su.pool, su.hf, su.opt_conf, su.ref
         n_ranks, cut, dropped = su.mesh.shape["model"], su.cut, su.dropped
         del su
         gc.collect()
         devs = jax.devices()
-        ref = TR.reference_readings(seed, hf, o, pool, devs, n_ranks)
+        ref = TR.reference_readings(reference, seed, hf, o, pool, devs, n_ranks)
         rows = [("program", prog)]
         if seed in args.control_seeds:
-            rows += [(k, TR.reference_readings(seed, hf, o, pool, devs, n_ranks, **kw))
+            rows += [(k, TR.reference_readings(reference, seed, hf, o, pool, devs, n_ranks, **kw))
                      for k, kw in KINDS]
         for kind, got in rows:
             numbers, left_out = TR.compare(got, ref)
